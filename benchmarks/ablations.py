@@ -24,6 +24,8 @@ OUT = "experiments/ablations"
 def _run(arch, shape, policy, tag):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # fake-device compiles by design: never contend for the parent's chip
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--out", OUT, "--tag", tag]
     if policy:

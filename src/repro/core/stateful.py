@@ -92,7 +92,7 @@ from repro.core.timing import Stopwatch
 from repro.core.pool import PipelinePool
 from repro.core.stages import abstractify, aval_fingerprint
 from repro.core.state_handoff import HandoffPlan, plan_handoff
-from repro.kernels import flash_decode as FD
+from repro.kernels import ops as kops
 from repro.models import layers as Lyr
 from repro.models import ssm as SSM
 from repro.models import transformer as T
@@ -237,7 +237,7 @@ class StatefulStageRunner:
         ``decode_impl``.  Both paths take/return (B, 1, H, hd) and accept
         a scalar or per-row ``(B,)`` decode position."""
         if self.resolved_decode_impl == "kernel":
-            return FD.flash_decode_attention(q, kc, vc, pos=pos + 1)
+            return kops.flash_decode_attention(q, kc, vc, pos + 1)
         return Lyr.decode_attention(q, kc, vc, pos=pos + 1)
 
     def _decode_rope(self, pos):
@@ -776,7 +776,9 @@ class StatefulStageRunner:
         ``shardings`` is the jit ``in_shardings`` tuple over
         ``(params, *args)`` (prefix pytrees allowed) and the cache keys on
         the mesh identity, so single-device and per-mesh executables for
-        the same range coexist."""
+        the same range coexist.  Its Pallas kernels are traced under
+        ``kernels.ops.over_mesh``: each shard runs them over the heads or
+        channels it holds (GSPMD cannot partition a Mosaic kernel)."""
         makers = {"decode": lambda: self._make_decode_fn(u0, u1),
                   "full": lambda: self._make_full_fn(u0, u1),
                   "embed": self._make_embed_fn,
@@ -794,7 +796,7 @@ class StatefulStageRunner:
             compiled = jax.jit(makers[mode]()).lower(
                 abstractify(params), *avals).compile()
         else:
-            with mesh:
+            with mesh, kops.over_mesh(mesh):
                 compiled = jax.jit(makers[mode](),
                                    in_shardings=shardings).lower(
                     abstractify(params), *avals).compile()

@@ -273,34 +273,36 @@ def decode_state_shardings(cfg: ArchConfig, mesh: Mesh, state):
     and ``ssm{i}`` (mamba1 (B, Di, N) / mamba2 (B, H, P, N)).
 
     Tensor-parallel only: serving batch is 1, so the dp axis replicates.
-    Non-divisible dims degrade to replication with a ``ShardingDegraded``
-    warning (same guard as ``param_shardings``)."""
+    KV caches split on whole KV heads only, never on ``head_dim``: the
+    decode kernel runs per shard (``kernels.ops``) and needs whole heads.
+    So GQA caches whose KV heads do not divide the axis (qwen2.5-3b's 2
+    on 4 chips) are replicated — each step then writes the new token's
+    K/V everywhere instead of gathering the cache.  Non-divisible dims
+    degrade to replication with a ``ShardingDegraded`` warning (same
+    guard as ``param_shardings``)."""
     _, tp = mesh_axes(mesh)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     tp_size = sizes.get("model", 1)
     degraded = []
 
-    def want(name: str, nd: int):
+    def tp_dim(name: str, nd: int):
         if name[0] in ("k", "v", "a") and nd == 4:   # (B, KH, S, hd)
-            return [(1, 3)]      # kv heads -> tp, else head_dim -> tp
+            return 1                                 # whole kv heads
         if name.startswith("conv"):                  # (B, K-1, C)
-            return [(nd - 1,)]
+            return nd - 1
         if name.startswith("ssm"):                   # channels/heads dim
-            return [(1,)]
-        return []
+            return 1
+        return None
 
     def rule(path, leaf):
         name = _path_str(path)
         spec = [None] * leaf.ndim
-        if tp is not None and tp_size > 1:
-            for dims in want(name, leaf.ndim):
-                hit = next((d for d in dims
-                            if leaf.shape[d] % tp_size == 0), None)
-                if hit is not None:
-                    spec[hit] = tp
-                else:
-                    degraded.append((name, dims[0], leaf.shape[dims[0]],
-                                     tp, tp_size))
+        d = tp_dim(name, leaf.ndim)
+        if tp is not None and tp_size > 1 and d is not None:
+            if leaf.shape[d] % tp_size == 0:
+                spec[d] = tp
+            else:
+                degraded.append((name, d, leaf.shape[d], tp, tp_size))
         return NamedSharding(mesh, P(*spec))
 
     out = jax.tree_util.tree_map_with_path(rule, state)

@@ -115,7 +115,7 @@ def flash_decode_attention(q, k_cache, v_cache, *, pos, block_k=512,
     # shared pos stays a (1,) SMEM scalar (the historic program); a (B,)
     # vector keeps one entry per batch row and flips the kernel into
     # per-row masking.  A size-1 vector is folded onto the scalar path so
-    # slot-count-1 pools compile the exact single-session program.
+    # slot-count-1 pools run the single-session kernel.
     per_row = jnp.ndim(pos) == 1 and pos.shape[0] > 1
     if per_row:
         pos_arr = pos.astype(jnp.int32).reshape(B)

@@ -23,40 +23,51 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, h0_ref, y_ref, hout_ref,
-                h_scr, *, chunk, num_chunks):
+def _ssd_kernel(rows_ref, cols_ref, b_ref, c_ref, x_ref, h0_ref, y_ref,
+                hout_ref, h_scr, *, chunk, num_chunks):
     cj = pl.program_id(2)
 
     @pl.when(cj == 0)
     def _init():
         h_scr[...] = h0_ref[0, 0].astype(jnp.float32)       # (P, N)
 
-    a = a_ref[0]                                            # scalar A_h < 0
-    dt = dt_ref[0, 0].astype(jnp.float32)                   # (chunk,)
+    # dt and cum = the chunk-local cumsum of dt*A arrive as rows and as
+    # columns (with tail = cum[-1] - cum): Mosaic tiles 2-D (sublane,
+    # lane) blocks, has no cumsum and cannot broadcast a (1, 1) value
+    # along both axes, so the scan and both orientations come from the
+    # wrapper
+    rows = rows_ref[0, 0]                                   # (2, chunk)
+    cols = cols_ref[0, 0]                                   # (chunk, 3)
+    dt_r, cum_r = rows[0:1, :], rows[1:2, :]                # (1, chunk)
+    dt_c, cum_c, tail_c = cols[:, 0:1], cols[:, 1:2], cols[:, 2:3]
     Bc = b_ref[0].astype(jnp.float32)                       # (chunk, N)
     Cc = c_ref[0].astype(jnp.float32)                       # (chunk, N)
     xh = x_ref[0, 0].astype(jnp.float32)                    # (chunk, P)
 
-    cum = jnp.cumsum(dt * a)                                # (chunk,)
-    alpha = jnp.exp(cum)
-    ratio = jnp.exp(cum[:, None] - cum[None, :])            # (t, s) <= 1
+    alpha = jnp.exp(cum_c)                                  # (chunk, 1)
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tril = (s_idx <= t_idx).astype(jnp.float32)
+    # decay ratio exp(cum_t - cum_s) <= 1 on the causal triangle; the
+    # exponent is masked BEFORE exp so s > t cannot overflow to inf * 0
+    ratio = jnp.exp(jnp.where(s_idx <= t_idx, cum_c - cum_r, -1e30))
     CB = jax.lax.dot_general(Cc, Bc, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    M = CB * ratio * dt[None, :] * tril                     # (chunk, chunk)
+    M = CB * ratio * dt_r                                   # (chunk, chunk)
     h = h_scr[...]
     y = jax.lax.dot_general(M, xh, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    y = y + alpha[:, None] * jax.lax.dot_general(
+    y = y + alpha * jax.lax.dot_general(
         Cc, h, (((1,), (1,)), ((), ())),                    # (chunk, P)
         preferred_element_type=jnp.float32)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    w = jnp.exp(cum[-1] - cum) * dt                         # (chunk,)
-    h_scr[...] = alpha[-1] * h + jax.lax.dot_general(
-        xh, Bc * w[:, None], (((0,), (0,)), ((), ())),      # (P, N)
+    w = jnp.exp(tail_c) * dt_c                              # (chunk, 1)
+    # exp(cum[-1]) as a (1, N) row: every entry of cum + tail is cum[-1]
+    alpha_last = jnp.max(jnp.broadcast_to(jnp.exp(cum_c + tail_c),
+                                          (chunk, h.shape[1])),
+                         axis=0, keepdims=True)
+    h_scr[...] = alpha_last * h + jax.lax.dot_general(
+        xh, Bc * w, (((0,), (0,)), ((), ())),               # (P, N)
         preferred_element_type=jnp.float32)
 
     @pl.when(cj == num_chunks - 1)
@@ -76,6 +87,8 @@ def ssd_scan(dt, Bc, Cc, x, A, h0=None, *, chunk=128, interpret=None):
         interpret = jax.default_backend() == "cpu"
     if h0 is None:
         h0 = jnp.zeros((B, H, P, N), jnp.float32)
+    # the TPU lowering needs a chunk that is a multiple of 128 or the
+    # whole (padded) sequence: min(128, S) is one or the other
     chunk = min(chunk, S)
     nc = -(-S // chunk)
     pad = nc * chunk - S
@@ -83,9 +96,15 @@ def ssd_scan(dt, Bc, Cc, x, A, h0=None, *, chunk=128, interpret=None):
     def padseq(arr):
         return jnp.pad(arr, ((0, 0), (0, pad)) + ((0, 0),) * (arr.ndim - 2))
 
-    # head-major layouts: dt (B,H,S), x (B,H,S,P)
-    dtp = padseq(dt).transpose(0, 2, 1)
-    xp = padseq(x).transpose(0, 2, 1, 3)
+    # padded steps carry dt = 0: decay 1, no update, so h_final is exact
+    dtp = padseq(dt).astype(jnp.float32)                    # (B, Sp, H)
+    cum = jnp.cumsum((dtp * A.astype(jnp.float32)).reshape(B, nc, chunk, H),
+                     axis=2).reshape(B, nc * chunk, H)
+    tail = (cum.reshape(B, nc, chunk, H)[:, :, -1:]
+            - cum.reshape(B, nc, chunk, H)).reshape(B, nc * chunk, H)
+    rows = jnp.stack([dtp, cum], axis=1).transpose(0, 3, 1, 2)   # (B,H,2,Sp)
+    cols = jnp.stack([dtp, cum, tail], axis=-1).transpose(0, 2, 1, 3)
+    xp = padseq(x).transpose(0, 2, 1, 3)                    # (B, H, Sp, P)
     Bp = padseq(Bc)
     Cp = padseq(Cc)
 
@@ -94,11 +113,11 @@ def ssd_scan(dt, Bc, Cc, x, A, h0=None, *, chunk=128, interpret=None):
         kernel,
         grid=(B, H, nc),                  # chunk dim innermost = sequential
         in_specs=[
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, 1, 2, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, chunk, 3), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -111,5 +130,5 @@ def ssd_scan(dt, Bc, Cc, x, A, h0=None, *, chunk=128, interpret=None):
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(dtp, Bp, Cp, xp, A.astype(jnp.float32), h0)
+    )(rows, cols, Bp, Cp, xp, h0)
     return y.transpose(0, 2, 1, 3)[:, :S], hout

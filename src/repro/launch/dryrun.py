@@ -29,6 +29,7 @@ from repro.distributed.roofline import (Roofline, collective_bytes,
 from repro.distributed.sharding import (cache_shardings, input_shardings,
                                         param_shardings,
                                         should_shard_fsdp_serving)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
 from repro.models.specs import input_specs
@@ -203,6 +204,7 @@ def main():
                     help='e.g. {"kv_layout": "seq"} — hillclimb variants')
     ap.add_argument("--tag", default="", help="suffix for variant records")
     args = ap.parse_args()
+    enable_compile_cache()
     policy = json.loads(args.policy_json) if args.policy_json else None
 
     pairs = []

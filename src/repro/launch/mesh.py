@@ -50,4 +50,8 @@ def make_cloud_mesh(shape):
             f"(set XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             f"before importing jax for CPU fake devices)")
     axes = ("model",) if len(shape) == 1 else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: GSPMD propagates shardings through the stage, the
+    # per-shard kernels' outputs included (``kernels.ops``); Explicit
+    # axes would type every intermediate and refuse the mixed layouts
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))

@@ -18,6 +18,7 @@ from repro.configs import get_config
 from repro.core import (BandwidthTrace, NeukonfigController, PipelineManager,
                         StageRunner, available_strategies, optimal_split,
                         profile_transformer)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving import (ServingEngine, VirtualClock, WallClock,
                            request_stream)
@@ -40,6 +41,7 @@ def main():
                          "behind schedule — measure with the default "
                          "virtual clock)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     params = T.init_model(cfg, jax.random.PRNGKey(0))

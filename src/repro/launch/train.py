@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training import train
 
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--checkpoint", default="")
     ap.add_argument("--checkpoint-every", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

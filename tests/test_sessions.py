@@ -10,7 +10,7 @@ The load-bearing invariants:
   bit-identical per slot against a no-switch control, with zero dropped
   sessions;
 * a slot-count-1 pool reproduces the single-session ``DecodeSession``
-  trajectory.
+  trajectory (tokens exactly, logits to float32 rounding).
 """
 import dataclasses
 
@@ -150,8 +150,14 @@ def test_slot_count_one_matches_decode_session():
     sid = sm.admit(prompt)
     for _ in range(3):
         mgrp.active.process()
-    np.testing.assert_array_equal(sm.logits_for(sid),
-                                  np.asarray(session.last_logits)[0])
+    # two compiled programs, not one: the pool admits through the masked
+    # fixed (1, max_seq) prefill and decodes with a (1,) position vector,
+    # the session prefills at the exact prompt length.  Their float sums
+    # are ordered differently (observed: 2.4e-7 on logits of order 1), so
+    # logits agree to float32 rounding and the greedy tokens exactly
+    np.testing.assert_allclose(sm.logits_for(sid),
+                               np.asarray(session.last_logits)[0],
+                               rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(sm.tokens_for(sid),
                                   np.asarray(session.tokens)[0])
     mgr1.close()

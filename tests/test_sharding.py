@@ -168,21 +168,24 @@ def test_input_and_cache_shardings_on_2d_mesh():
 
 @needs_devices
 def test_decode_state_shardings_rules():
-    """Live-session layouts: kv heads -> tp when divisible, head_dim for
-    GQA, conv channel dim, ssm channel dim; dp always replicated."""
+    """Live-session layouts: kv heads -> tp when divisible, GQA caches
+    with fewer kv heads replicated (the per-shard decode kernel needs
+    whole heads, so head_dim is never split), conv channel dim, ssm
+    channel dim; dp always replicated."""
     cfg = get_config("qwen2.5-3b")
     mesh = make_cloud_mesh((4,))
     st = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
     state = {
         "k0": st(1, 8, 64, 128),      # KH=8 divides tp=4 -> dim 1
-        "v1": st(1, 2, 64, 128),      # GQA KH=2: falls to head_dim dim 3
+        "v1": st(1, 2, 64, 128),      # GQA KH=2: replicated, with a warning
         "conv0": st(1, 3, 256),       # channels (last dim) -> tp
         "ssm0": st(1, 256, 16),       # mamba channel dim 1 -> tp
     }
-    sh = decode_state_shardings(cfg, mesh, state)
+    with pytest.warns(ShardingDegraded, match=r"v1\[dim 1\]=2"):
+        sh = decode_state_shardings(cfg, mesh, state)
     P = jax.sharding.PartitionSpec
     assert sh["k0"].spec == P(None, "model", None, None)
-    assert sh["v1"].spec == P(None, None, None, "model")
+    assert sh["v1"].spec == P(None, None, None, None)
     assert sh["conv0"].spec == P(None, None, "model")
     assert sh["ssm0"].spec == P(None, "model", None)
 
