@@ -20,6 +20,9 @@ import aliases), every project-local function it calls, flagging:
 
 * **impure calls**: ``time.*``, ``random.*``, ``np.random.*``, ``print``,
   ``open``, ``input`` — trace-time side effects frozen into the graph;
+  and the program's own spans and counters (``repro.core.timing``:
+  ``timing.span``, ``timing.count``, ...), which would record once, at
+  trace time, and never on a call;
 * **host coercions**: ``float(x)`` / ``int(x)`` on non-literal values and
   ``.item()`` — host syncs inside traced code;
 * **non-static static_argnums/static_argnames**: the ``jax.jit`` call
@@ -39,7 +42,7 @@ from repro.analysis.core import (Finding, Module, Project, Rule,
                                  dotted_name, import_aliases)
 
 IMPURE_PREFIXES = ("time.", "random.", "np.random.", "numpy.random.",
-                   "os.urandom")
+                   "os.urandom", "repro.core.timing.")
 IMPURE_BARE = frozenset({"print", "open", "input"})
 # environment queries: legal Python, but the answer is frozen at trace
 # time — almost always a bug unless deliberately chosen per-backend
@@ -188,7 +191,7 @@ class TracingHygieneRule(Rule):
             if name is not None:
                 resolved = aliases.get(name.split(".")[0],
                                        name.split(".")[0])
-                full = name if "." not in name else \
+                full = resolved if "." not in name else \
                     f"{resolved}.{name.split('.', 1)[1]}"
                 if name in IMPURE_BARE:
                     findings.append(module.finding(

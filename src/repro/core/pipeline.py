@@ -21,10 +21,9 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional
 
 import jax
-import numpy as np
 
 from repro.core.hardware import CLOUD_SPEC, EDGE_SPEC
-from repro.core.timing import Stopwatch
+from repro.core import timing
 from repro.core.network import NetworkModel
 from repro.core.stages import StageRunner, abstractify, aval_fingerprint
 
@@ -62,13 +61,26 @@ class BuildReport:
     t_compile_edge: float = 0.0
     t_compile_cloud: float = 0.0
     t_reshard: float = 0.0        # cloud-weight placement onto the mesh
-    t_wall: float = 0.0           # end-to-end build wall time; less than
+    t_wall: float = 0.0           # the ``pool.build`` span's wall, set by
+                                  # ``PipelinePool.ensure``; less than
                                   # ``total`` when the stages overlapped
 
     @property
     def total(self) -> float:
         return (self.t_weights + self.t_compile_edge + self.t_compile_cloud
                 + self.t_reshard)
+
+
+def tree_bytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
+def copy_to_device(tree):
+    """A second device copy of ``tree`` through host memory (a new
+    container's own weights), its traffic counted."""
+    out = jax.tree.map(lambda a: jax.device_put(timing.fetch(a)), tree)
+    timing.count("h2d_bytes", tree_bytes(tree))
+    return out
 
 
 class EdgeCloudPipeline:
@@ -117,35 +129,32 @@ class EdgeCloudPipeline:
         """
         rep = BuildReport()
         r = self.runner
-        if reload_from is not None:
-            from repro.checkpoint import load_pytree
-            sw = Stopwatch()
-            self.params = load_pytree(reload_from, like=r.params)
-            jax.block_until_ready(self.params)
-            rep.t_weights = sw.elapsed()
-        elif self.owns_weights:
-            sw = Stopwatch()
-            self.params = jax.tree.map(
-                lambda a: jax.device_put(np.asarray(a)), r.params)
-            jax.block_until_ready(self.params)
-            rep.t_weights = sw.elapsed()
+        if reload_from is not None or self.owns_weights:
+            with timing.span("build.weights", timed=True,
+                             bytes=tree_bytes(r.params)) as m:
+                if reload_from is not None:
+                    from repro.checkpoint import load_pytree
+                    self.params = load_pytree(reload_from, like=r.params)
+                else:
+                    self.params = copy_to_device(r.params)
+                timing.block(self.params)
+            rep.t_weights = m.wall
         else:
             self.params = r.params
 
         lo_e, hi_e = 0, self.split + 1
         lo_c, hi_c = self.split + 1, r.num_units
-        sw_wall = Stopwatch()
         in_avals = abstractify(sample_inputs)
         edge_box: Dict[str, Any] = {}
 
         def _compile_edge():
-            sw_edge = Stopwatch()
-            try:
-                edge_box["fn"] = r.stage_executable(
-                    lo_e, hi_e, self.params, in_avals, fresh=cold)
-            except BaseException as e:
-                edge_box["error"] = e
-            rep.t_compile_edge = sw_edge.elapsed()
+            with timing.span("build.exec", timed=True, stage="edge") as m:
+                try:
+                    edge_box["fn"] = r.stage_executable(
+                        lo_e, hi_e, self.params, in_avals, fresh=cold)
+                except BaseException as e:
+                    edge_box["error"] = e
+            rep.t_compile_edge = m.wall
 
         # edge compiles on a helper thread while this thread derives the
         # boundary aval (an eval_shape trace — the sample never executes)
@@ -153,32 +162,33 @@ class EdgeCloudPipeline:
         # compilations genuinely overlap when the host has cores to spare
         th = None
         if PARALLEL_BUILD:
-            th = threading.Thread(target=_compile_edge,
+            th = threading.Thread(target=timing.carry(_compile_edge),
                                   name="edge-stage-compile")
             th.start()
-        sw_cloud = Stopwatch()
-        mid_avals = r.stage_out_avals(lo_e, hi_e, self.params, in_avals)
-        if self.mesh_shape is None:
-            self.cloud_params = self.params
-            self._cloud_psh = self._cloud_in_shardings = None
-            cloud_fn = r.stage_executable(lo_c, hi_c, self.params, mid_avals,
-                                          fresh=cold)
-        else:
-            from repro.launch.mesh import make_cloud_mesh
-            mesh = make_cloud_mesh(self.mesh_shape)
-            psh, ssh = r.stage_shardings(mesh, mid_avals)
-            self._cloud_psh, self._cloud_in_shardings = psh, ssh
-            cloud_fn = r.stage_executable(lo_c, hi_c, self.params, mid_avals,
-                                          fresh=cold, shardings=(psh, ssh),
-                                          mesh=mesh)
+        with timing.span("build.exec", timed=True, stage="cloud") as m:
+            mid_avals = r.stage_out_avals(lo_e, hi_e, self.params, in_avals)
+            if self.mesh_shape is None:
+                self.cloud_params = self.params
+                self._cloud_psh = self._cloud_in_shardings = None
+                cloud_fn = r.stage_executable(lo_c, hi_c, self.params,
+                                              mid_avals, fresh=cold)
+            else:
+                from repro.launch.mesh import make_cloud_mesh
+                mesh = make_cloud_mesh(self.mesh_shape)
+                psh, ssh = r.stage_shardings(mesh, mid_avals)
+                self._cloud_psh, self._cloud_in_shardings = psh, ssh
+                cloud_fn = r.stage_executable(lo_c, hi_c, self.params,
+                                              mid_avals, fresh=cold,
+                                              shardings=(psh, ssh), mesh=mesh)
+        rep.t_compile_cloud = m.wall
+        if self.mesh_shape is not None:
             # the cloud container's weight copy lives ON the mesh; placing
             # it here (at build time) is what lets prebuilt standbys pay
             # the reshard off the stream
-            sw = Stopwatch()
-            self.cloud_params = jax.device_put(self.params, psh)
-            jax.block_until_ready(self.cloud_params)
-            rep.t_reshard = sw.elapsed()
-        rep.t_compile_cloud = sw_cloud.elapsed() - rep.t_reshard
+            with timing.span("build.reshard", timed=True) as m:
+                self.cloud_params = jax.device_put(self.params, psh)
+                timing.block(self.cloud_params)
+            rep.t_reshard = m.wall
         if th is not None:
             th.join()
         else:
@@ -188,7 +198,6 @@ class EdgeCloudPipeline:
         self.edge_fn, self.cloud_fn = edge_box["fn"], cloud_fn
         self._edge_avals = aval_fingerprint(in_avals)
         self._cloud_avals = aval_fingerprint(mid_avals)
-        rep.t_wall = rep.t_weights + sw_wall.elapsed()
         return rep
 
     def reshard(self) -> int:
@@ -277,18 +286,19 @@ class EdgeCloudPipeline:
     def process(self, inputs, *, batch: int = 1, seq: Optional[int] = None
                 ) -> tuple[Any, RequestTiming]:
         assert self.ready, "pipeline not built"
-        sw = Stopwatch()
-        h = self._run_edge(inputs)
-        jax.block_until_ready(h)
-        t_edge = sw.elapsed() * self.edge_scale
-        if seq is None:
-            seq = inputs["tokens"].shape[1] if "tokens" in inputs else 1
-        bbytes = self.runner.boundary_bytes(self.split, batch, seq)
-        t_transfer = self.net.transfer_time(bbytes)
-        sw = Stopwatch()
-        out = self._run_cloud(h)
-        jax.block_until_ready(out)
-        t_cloud = sw.elapsed()
+        with timing.span("step"):
+            with timing.span("step.edge", timed=True) as m:
+                h = self._run_edge(inputs)
+                timing.block(h)
+            t_edge = m.wall * self.edge_scale
+            if seq is None:
+                seq = inputs["tokens"].shape[1] if "tokens" in inputs else 1
+            bbytes = self.runner.boundary_bytes(self.split, batch, seq)
+            t_transfer = self.net.transfer_time(bbytes)
+            with timing.span("step.cloud", timed=True) as m:
+                out = self._run_cloud(h)
+                timing.block(out)
+            t_cloud = m.wall
         return out["logits"], RequestTiming(t_edge, t_transfer, t_cloud)
 
     # -- memory accounting (Table I) --------------------------------------

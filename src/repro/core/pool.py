@@ -386,30 +386,33 @@ class PipelinePool:
                 if cached is not None and cached.pipeline.ready:
                     self._touch(cached)
                     return cached, True
-        plan = self.fault_plan
-        if plan is not None:
-            # chaos valve: may raise InjectedBuildFailure or stall.
-            # Outside the pool lock, like the build it gates.
-            plan.on_build(key)
-        pipe = self._new_pipeline(key)
-        report = pipe.build(self.sample_inputs, cold=cold,
-                            reload_from=reload_from)
-        with self._lock:
-            replaced = self._entries.get(key)
-            if replaced is not None:
-                # rebuilding the active key orphans the old active object the
-                # moment the dict entry is swapped (``self.active`` resolves
-                # through ``_entries``), so it must be closed either way —
-                # keeping it alive was a leak
-                replaced.pipeline.close()
-            entry = PoolEntry(key, pipe, report)
-            self._entries[key] = entry
-            self._touch(entry)
-            # never evict the entry we were asked for — callers may be about
-            # to activate it; speculative builders re-run evict_to_budget()
-            # themselves
-            self.evict_to_budget(keep=key)
-            self._evict_over_capacity(keep=key)
+        with timing.span("pool.build", timed=True, split=key.split,
+                         owns_weights=key.owns_weights, cold=cold) as m:
+            plan = self.fault_plan
+            if plan is not None:
+                # chaos valve: may raise InjectedBuildFailure or stall.
+                # Outside the pool lock, like the build it gates.
+                plan.on_build(key)
+            pipe = self._new_pipeline(key)
+            report = pipe.build(self.sample_inputs, cold=cold,
+                                reload_from=reload_from)
+            with self._lock:
+                replaced = self._entries.get(key)
+                if replaced is not None:
+                    # rebuilding the active key orphans the old active
+                    # object the moment the dict entry is swapped
+                    # (``self.active`` resolves through ``_entries``), so it
+                    # must be closed either way — keeping it alive was a leak
+                    replaced.pipeline.close()
+                entry = PoolEntry(key, pipe, report)
+                self._entries[key] = entry
+                self._touch(entry)
+                # never evict the entry we were asked for — callers may be
+                # about to activate it; speculative builders re-run
+                # evict_to_budget() themselves
+                self.evict_to_budget(keep=key)
+                self._evict_over_capacity(keep=key)
+        report.t_wall = m.wall
         return entry, False
 
     def resolve_standby_ownership(self, owns_weights: Optional[bool]) -> bool:
@@ -500,7 +503,8 @@ class PipelinePool:
                         self.evict_to_budget(reap_pending=(key,))
                 return entry
 
-            handle = self.executor.submit(job, key=key)
+            # the build's spans on the worker name this one as their cause
+            handle = self.executor.submit(timing.carry(job), key=key)
             self._pending[key] = handle
             if standby:
                 self._standby_handle = handle
@@ -547,18 +551,19 @@ class PipelinePool:
         """Deterministic barrier: wait for every pending build, then warn
         (on this thread) for any that failed."""
         deadline = None if timeout is None else timing.now() + timeout
-        while True:
-            with self._lock:
-                handles = list(self._pending.values())
-            if not handles:
-                break
-            for h in handles:
-                left = None if deadline is None \
-                    else max(0.0, deadline - timing.now())
-                if not h.wait(left) and deadline is not None:
+        with timing.span("pool.drain"):
+            while True:
+                with self._lock:
+                    handles = list(self._pending.values())
+                if not handles:
                     break
-            if deadline is not None and timing.now() >= deadline:
-                break
+                for h in handles:
+                    left = None if deadline is None \
+                        else max(0.0, deadline - timing.now())
+                    if not h.wait(left) and deadline is not None:
+                        break
+                if deadline is not None and timing.now() >= deadline:
+                    break
         self._surface_failures()
 
     def close(self) -> None:
@@ -605,18 +610,20 @@ class PipelinePool:
             assert entry.pipeline.ready, f"pipeline {key} not built"
             old_key = self.active_key if self.active_key is not None \
                 else self._paused_key
-            sw = timing.Stopwatch()
             reshard = None
-            if old_key is not None and old_key.mesh_shape != key.mesh_shape:
-                rsw = timing.Stopwatch()
-                moved = entry.pipeline.reshard()
-                reshard = ReshardReport(old_mesh=old_key.mesh_shape,
-                                        new_mesh=key.mesh_shape,
-                                        t_wall=rsw.elapsed(),
-                                        moved_bytes=moved)
-            self.active_key = key
-            self._paused_key = None
-            t_switch = sw.elapsed()
+            with timing.span("pool.activate", timed=True,
+                             split=key.split) as m:
+                if old_key is not None \
+                        and old_key.mesh_shape != key.mesh_shape:
+                    with timing.span("pool.reshard", timed=True) as rm:
+                        moved = entry.pipeline.reshard()
+                    reshard = ReshardReport(old_mesh=old_key.mesh_shape,
+                                            new_mesh=key.mesh_shape,
+                                            t_wall=rm.wall,
+                                            moved_bytes=moved)
+                self.active_key = key
+                self._paused_key = None
+            t_switch = m.wall
             if self.standby_key == key:
                 self.standby_key = None
             if reshard is not None:

@@ -72,7 +72,6 @@ family is excluded from slot pools).
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 import zlib
 from dataclasses import dataclass
@@ -87,8 +86,9 @@ from repro.core.concurrency import (RANK_SESSION, RANK_STATEFUL_RUNNER,
                                     guarded_by, make_lock)
 from repro.core.hardware import CLOUD_SPEC, EDGE_SPEC
 from repro.core.network import NetworkModel
-from repro.core.pipeline import BuildReport, RequestTiming
-from repro.core.timing import Stopwatch
+from repro.core import timing
+from repro.core.pipeline import (BuildReport, RequestTiming, copy_to_device,
+                                 tree_bytes)
 from repro.core.pool import PipelinePool
 from repro.core.stages import abstractify, aval_fingerprint
 from repro.core.state_handoff import HandoffPlan, plan_handoff
@@ -865,11 +865,11 @@ class DecodeSession:
         jax.block_until_ready(logits)
         # calibration wall from a second, warm run: the first call paid
         # jit compilation, which would make the recompute arm look orders
-        # of magnitude slower than it is.  Deliberately raw wall (never
-        # stream time): this prices THIS HOST's recompute throughput.
-        t0 = time.perf_counter()    # nk: allow[NK02]: host calibration
-        jax.block_until_ready(r.full_fn(0, U)(r.params, x)[0])
-        wall = time.perf_counter() - t0     # nk: allow[NK02]
+        # of magnitude slower than it is.  Host wall (never stream time):
+        # this prices THIS HOST's recompute throughput.
+        with timing.measure() as m:
+            jax.block_until_ready(r.full_fn(0, U)(r.params, x)[0])
+        wall = m.wall
         with self._lock:
             self.cache = dict(caches)
             self.tokens = np.asarray(tokens)
@@ -908,13 +908,13 @@ class DecodeSession:
         round_trip(L)                       # warm dispatch paths
 
         def timed(hi):
-            # deliberately raw wall: calibrates THIS HOST's serialization
-            # throughput for hand-off pricing, never charged to the stream
+            # host wall: calibrates THIS HOST's serialization throughput
+            # for hand-off pricing, never charged to the stream
             best, n = float("inf"), 0
             for _ in range(3):              # min-of-3: robust to GC spikes
-                t0 = time.perf_counter()    # nk: allow[NK02]: calibration
-                n = round_trip(hi)
-                best = min(best, time.perf_counter() - t0)  # nk: allow[NK02]
+                with timing.measure() as m:
+                    n = round_trip(hi)
+                best = min(best, m.wall)
             return best, n
         t_full, n_full = timed(L)
         t_half, n_half = timed(half)
@@ -958,9 +958,9 @@ class DecodeSession:
         with self._lock:
             self.cache.update(new_state)
             self.tokens = np.concatenate(
-                [self.tokens, np.asarray(token)], axis=1)
+                [self.tokens, timing.fetch(token)], axis=1)
             self.bounds = np.concatenate(
-                [self.bounds, np.asarray(bounds)], axis=2)
+                [self.bounds, timing.fetch(bounds)], axis=2)
             self.last_logits = logits
             self.pos += 1
             self.epoch += 1
@@ -991,7 +991,7 @@ class DecodeSession:
         with self._lock:
             for unit in self.runner.units[u0:u1]:
                 for k in _unit_state_keys(self.cfg, unit):
-                    arr = np.asarray(self.cache[k])
+                    arr = timing.fetch(self.cache[k])
                     if k[0] in ("k", "v", "a"):      # KV: valid region only
                         arr = arr[:, :, :self.pos]
                     buf = arr.tobytes()
@@ -1046,9 +1046,9 @@ class DecodeSession:
                 if k[0] in ("k", "v", "a"):
                     full = np.zeros(self.cache[k].shape, arr.dtype)
                     full[:, :, :arr.shape[2]] = arr
-                    self.cache[k] = jnp.asarray(full)
+                    self.cache[k] = timing.upload(full)
                 else:
-                    self.cache[k] = jnp.asarray(arr)
+                    self.cache[k] = timing.upload(arr)
 
     def recompute_layers(self, lo: int, hi: int) -> None:
         """Re-prefill layers [lo, hi) over the full live context from the
@@ -1066,9 +1066,9 @@ class DecodeSession:
         B, T_len, D = x0.shape
         x_pad = np.zeros((B, r.max_seq, D), x0.dtype)
         x_pad[:, :T_len] = x0
-        caches = r.recompute_fn(u0, u1)(r.params, jnp.asarray(x_pad),
+        caches = r.recompute_fn(u0, u1)(r.params, timing.upload(x_pad),
                                         jnp.int32(T_len))
-        jax.block_until_ready(caches)
+        timing.block(caches)
         with self._lock:
             self.cache.update(caches)
 
@@ -1140,18 +1140,16 @@ class StatefulEdgeCloudPipeline:
               reload_from: Optional[str] = None) -> BuildReport:
         rep = BuildReport()
         r = self.runner
-        if reload_from is not None:
-            from repro.checkpoint import load_pytree
-            sw = Stopwatch()
-            self.params = load_pytree(reload_from, like=r.params)
-            jax.block_until_ready(self.params)
-            rep.t_weights = sw.elapsed()
-        elif self.owns_weights:
-            sw = Stopwatch()
-            self.params = jax.tree.map(
-                lambda a: jax.device_put(np.asarray(a)), r.params)
-            jax.block_until_ready(self.params)
-            rep.t_weights = sw.elapsed()
+        if reload_from is not None or self.owns_weights:
+            with timing.span("build.weights", timed=True,
+                             bytes=tree_bytes(r.params)) as m:
+                if reload_from is not None:
+                    from repro.checkpoint import load_pytree
+                    self.params = load_pytree(reload_from, like=r.params)
+                else:
+                    self.params = copy_to_device(r.params)
+                timing.block(self.params)
+            rep.t_weights = m.wall
         else:
             self.params = r.params
 
@@ -1165,23 +1163,19 @@ class StatefulEdgeCloudPipeline:
         # scalar for the single-stream session, (num_slots,) for slot
         # pools — the compiled stages follow the session's position shape
         pos_av = jax.ShapeDtypeStruct(jnp.shape(s.step_pos()), jnp.int32)
-        sw_wall = Stopwatch()
-        sw = Stopwatch()
-        self.embed_fn = r.executable("embed", 0, 0, self.params, tok_av,
-                                     fresh=cold)
-        self.edge_fn = r.executable(
-            "decode", 0, self._u_edge, self.params, x_av,
-            s.subset(0, self._u_edge), pos_av, fresh=cold)
-        rep.t_compile_edge = sw.restart()
+        with timing.span("build.exec", timed=True, stage="embed") as m_embed:
+            self.embed_fn = r.executable("embed", 0, 0, self.params, tok_av,
+                                         fresh=cold)
+        with timing.span("build.exec", timed=True, stage="edge") as m_edge:
+            self.edge_fn = r.executable(
+                "decode", 0, self._u_edge, self.params, x_av,
+                s.subset(0, self._u_edge), pos_av, fresh=cold)
+        rep.t_compile_edge = m_embed.wall + m_edge.wall
         cache_cloud = s.subset(self._u_edge, self._u_all)
+        shardings = head_shardings = mesh = None
         if self.mesh_shape is None:
             self.cloud_params = self.params
             self._cloud_psh = self._cloud_state_shardings = self._repl = None
-            self.cloud_fn = r.executable(
-                "decode", self._u_edge, self._u_all, self.params, x_av,
-                cache_cloud, pos_av, fresh=cold)
-            self.head_fn = r.executable("head", 0, 0, self.params, x_av,
-                                        fresh=cold)
         else:
             from jax.sharding import NamedSharding, PartitionSpec
             from repro.distributed.sharding import (decode_state_shardings,
@@ -1195,24 +1189,25 @@ class StatefulEdgeCloudPipeline:
             repl = NamedSharding(mesh, PartitionSpec())
             self._cloud_psh, self._cloud_state_shardings = psh, csh
             self._repl = repl
+            shardings, head_shardings = (psh, repl, csh, repl), (psh, repl)
+        with timing.span("build.exec", timed=True, stage="cloud") as m_cloud:
             self.cloud_fn = r.executable(
                 "decode", self._u_edge, self._u_all, self.params, x_av,
-                cache_cloud, pos_av, fresh=cold,
-                shardings=(psh, repl, csh, repl), mesh=mesh)
+                cache_cloud, pos_av, fresh=cold, shardings=shardings,
+                mesh=mesh)
+        with timing.span("build.exec", timed=True, stage="head") as m_head:
             self.head_fn = r.executable("head", 0, 0, self.params, x_av,
-                                        fresh=cold, shardings=(psh, repl),
+                                        fresh=cold, shardings=head_shardings,
                                         mesh=mesh)
-            rep.t_compile_cloud = sw.elapsed()
-            # place the cloud weight copy + the live cloud-range decode
-            # state on the mesh at build time, so a prebuilt standby's
-            # on-stream reshard is ~0
-            swr = Stopwatch()
-            self.cloud_params = jax.device_put(self.params, psh)
-            jax.block_until_ready(self.cloud_params)
-            rep.t_reshard = swr.elapsed()
-        if rep.t_compile_cloud == 0.0:
-            rep.t_compile_cloud = sw.elapsed() - rep.t_reshard
-        rep.t_wall = rep.t_weights + sw_wall.elapsed()
+        rep.t_compile_cloud = m_cloud.wall + m_head.wall
+        if mesh is not None:
+            # place the cloud weight copy on the mesh at build time, so a
+            # prebuilt standby's on-stream reshard is ~0
+            with timing.span("build.reshard", timed=True) as m:
+                self.cloud_params = jax.device_put(self.params,
+                                                   self._cloud_psh)
+                timing.block(self.cloud_params)
+            rep.t_reshard = m.wall
         return rep
 
     @property
@@ -1276,14 +1271,30 @@ class StatefulEdgeCloudPipeline:
             # the previous step's logits (hence this argmax token) may be
             # mesh-resident; the edge embed is compiled single-device
             token = jax.device_put(token, edge_sh)
-        sw = Stopwatch()
-        x = self.embed_fn(self.params, token)
-        xe, new_e, b_e = self.edge_fn(self.params, x, cache_edge, pos)
-        jax.block_until_ready(xe)
-        t_edge = sw.elapsed() * self.edge_scale
+        with timing.span("step.edge", timed=True) as m:
+            x = self.embed_fn(self.params, token)
+            xe, new_e, b_e = self.edge_fn(self.params, x, cache_edge, pos)
+            timing.block(xe)
+        t_edge = m.wall * self.edge_scale
         t_transfer = self.net.transfer_time(
             int(np.prod(xe.shape)) * xe.dtype.itemsize)
-        sw = Stopwatch()
+        with timing.span("step.cloud", timed=True) as m:
+            new_c, b_c, logits = self._run_cloud(xe, cache_cloud, pos)
+        t_cloud = m.wall
+        if self._repl is not None:
+            # mesh-resident and edge-resident bounds cannot mix in one
+            # jnp.concatenate (device mismatch); the session stores numpy
+            # anyway
+            bounds = np.concatenate([timing.fetch(b_e), timing.fetch(b_c)],
+                                    axis=0)
+        else:
+            bounds = jnp.concatenate([b_e, b_c], axis=0)
+        return logits, {**new_e, **new_c}, bounds, \
+            RequestTiming(t_edge, t_transfer, t_cloud)
+
+    def _run_cloud(self, xe, cache_cloud, pos):
+        """The cloud stage and the LM head, through their sync."""
+        edge_sh = self._edge_sharding
         if self._cloud_state_shardings is not None:
             # the edge->cloud hop: AOT executables do not auto-reshard, so
             # the boundary token, position and any state entry not already
@@ -1307,18 +1318,8 @@ class StatefulEdgeCloudPipeline:
             # output sharding is whatever GSPMD propagated
             xc = jax.device_put(xc, self._repl)
         logits = self.head_fn(self.cloud_params, xc)
-        jax.block_until_ready(logits)
-        t_cloud = sw.elapsed()
-        if self._repl is not None:
-            # mesh-resident and edge-resident bounds cannot mix in one
-            # jnp.concatenate (device mismatch); the session stores numpy
-            # anyway
-            bounds = np.concatenate([np.asarray(b_e), np.asarray(b_c)],
-                                    axis=0)
-        else:
-            bounds = jnp.concatenate([b_e, b_c], axis=0)
-        return logits, {**new_e, **new_c}, bounds, \
-            RequestTiming(t_edge, t_transfer, t_cloud)
+        timing.block(logits)
+        return new_c, b_c, logits
 
     def process(self, inputs=None, *, batch: int = 1, seq=None
                 ) -> tuple:
@@ -1328,17 +1329,21 @@ class StatefulEdgeCloudPipeline:
         if s.pos >= self.runner.max_seq:
             raise RuntimeError(f"decode context full ({s.pos} >= "
                                f"max_seq {self.runner.max_seq})")
-        token = None
-        if isinstance(inputs, dict):
-            token = inputs.get("token")
-        if token is None:
-            token = s.next_token()
-        pos = s.step_pos()
-        logits, new, bounds, timing = self._step(
-            jnp.asarray(token, jnp.int32), s.subset(0, self._u_edge),
-            s.subset(self._u_edge, self._u_all), pos)
-        s.commit_step(token, new, bounds, logits)
-        return logits, timing
+        with timing.span("step"):
+            with timing.span("step.input"):
+                token = None
+                if isinstance(inputs, dict):
+                    token = inputs.get("token")
+                if token is None:
+                    token = s.next_token()
+                pos = s.step_pos()
+                cache_edge = s.subset(0, self._u_edge)
+                cache_cloud = s.subset(self._u_edge, self._u_all)
+            logits, new, bounds, req = self._step(
+                jnp.asarray(token, jnp.int32), cache_edge, cache_cloud, pos)
+            with timing.span("step.commit"):
+                s.commit_step(token, new, bounds, logits)
+        return logits, req
 
     def warm(self, sample_inputs=None) -> RequestTiming:
         """Throwaway forward on SCRATCH state: absorbs the first-execution
@@ -1346,11 +1351,11 @@ class StatefulEdgeCloudPipeline:
         s = self.session
         zeros = lambda t: jax.tree.map(jnp.zeros_like, t)
         tok = jnp.zeros((s.batch, 1), jnp.int32)
-        _, _, _, timing = self._step(
+        _, _, _, req = self._step(
             tok, zeros(s.subset(0, self._u_edge)),
             zeros(s.subset(self._u_edge, self._u_all)),
             jnp.zeros_like(s.step_pos()))
-        return timing
+        return req
 
     # -- memory accounting ------------------------------------------------
     def live_param_bytes(self) -> int:
@@ -1429,28 +1434,36 @@ class StatefulPipelinePool(PipelinePool):
         mode = self.force_mode or plan.best
         lo, hi = min(old_split, new_split), max(old_split, new_split)
         fallback = False
-        sw = Stopwatch()
+        t_wall = 0.0                # the walls of the handoff.* spans
         if mode == "transfer":
-            payload, nbytes = s.export_layers(lo, hi)
-            fplan = self.fault_plan
-            if fplan is not None:
-                # chaos valve: in-transit corruption/truncation
-                fplan.mutate_handoff(payload, epoch=s.epoch)
+            with timing.span("handoff.export", timed=True, mode=mode,
+                             layers=hi - lo) as m:
+                payload, nbytes = s.export_layers(lo, hi)
+                fplan = self.fault_plan
+                if fplan is not None:
+                    # chaos valve: in-transit corruption/truncation
+                    fplan.mutate_handoff(payload, epoch=s.epoch)
+            t_wall += m.wall
             # the (possibly corrupt) payload really crossed the link, so
             # its priced seconds stand even when validation rejects it
             t_network = self.net.transfer_time(nbytes)
             try:
-                s.import_layers(payload)
+                with timing.span("handoff.import", timed=True, mode=mode,
+                                 layers=hi - lo, bytes=nbytes) as m:
+                    s.import_layers(payload)
             except HandoffCorrupted as e:
                 warnings.warn(f"hand-off payload failed validation ({e}); "
                               f"recovering via masked recompute",
                               HandoffIntegrityWarning)
-                s.recompute_layers(lo, hi)
                 mode, fallback = "recompute", True
+            t_wall += m.wall
         else:
-            s.recompute_layers(lo, hi)
             nbytes, t_network = 0, 0.0
-        t_wall = sw.elapsed()
+        if mode != "transfer":      # chosen, forced, or the fallback
+            with timing.span("handoff.recompute", timed=True, mode=mode,
+                             layers=hi - lo) as m:
+                s.recompute_layers(lo, hi)
+            t_wall += m.wall
         return HandoffReport(mode, hi - lo, nbytes, t_wall, t_network,
                              plan, s.epoch, fallback=fallback)
 

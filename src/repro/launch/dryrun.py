@@ -16,7 +16,6 @@ before jax initialises devices):
 import argparse
 import functools
 import json
-import time
 import traceback
 
 import jax
@@ -24,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs import (ASSIGNED_ARCHS, INPUT_SHAPES, get_config,
                            get_shape, pair_is_runnable)
+from repro.core import timing
 from repro.distributed.roofline import (Roofline, collective_bytes,
                                         model_flops_estimate)
 from repro.distributed.sharding import (cache_shardings, input_shardings,
@@ -165,10 +165,11 @@ def analyse(arch, shape_name, lowered, compiled, meta, *, multi_pod):
 
 def run_pair(arch, shape_name, *, multi_pod, out_dir, policy=None,
              tag=""):
-    t0 = time.perf_counter()
-    lowered, compiled, meta = lower_pair(arch, shape_name,
-                                         multi_pod=multi_pod, policy=policy)
-    t_compile = time.perf_counter() - t0
+    with timing.measure() as m:
+        lowered, compiled, meta = lower_pair(arch, shape_name,
+                                             multi_pod=multi_pod,
+                                             policy=policy)
+    t_compile = m.wall
     rl = analyse(arch, shape_name, lowered, compiled, meta,
                  multi_pod=multi_pod)
     rec = rl.to_dict()

@@ -78,6 +78,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
+from repro.core import timing
 from repro.core.executor import BuildHandle
 from repro.core.network import NetworkModel
 from repro.core.pool import SwitchAbortedWarning
@@ -218,7 +219,7 @@ class ServingEngine:
         if sess is None or not hasattr(sess, "admit"):
             raise RuntimeError("scheduled admission needs a slot-pool "
                                "session (make_session_manager)")
-        with self.clock.measure():
+        with timing.span("engine.admit", sid=sid), self.clock.measure():
             out = sess.admit(prompt, sid=sid)
         self._blocked_until = max(self._blocked_until, self.clock.now())
         return out
@@ -231,6 +232,14 @@ class ServingEngine:
         completing after it) drain on the old pipeline.
         """
         strategy = self.mgr.get_strategy(strategy)
+        active = self.pool.active
+        with timing.span("engine.switch", switch=len(self.reports),
+                         strategy=getattr(strategy, "spec", str(strategy)),
+                         new=new_split,
+                         old=active.split if active is not None else None):
+            return self._execute_switch(strategy, new_split)
+
+    def _execute_switch(self, strategy, new_split: int):
         if not self.overlap:
             # the gap since the previous switch was stream-seconds long;
             # background builds finished during it (not charged to the
@@ -288,8 +297,9 @@ class ServingEngine:
         """
         if self.switch_timeout_s is None:
             return strategy.switch(self.pool, new_split)
-        handle = BuildHandle(lambda: strategy.switch(self.pool, new_split),
-                             key=("switch", new_split))
+        handle = BuildHandle(
+            timing.carry(lambda: strategy.switch(self.pool, new_split)),
+            key=("switch", new_split))
         th = threading.Thread(target=handle._run, name="nk-switch",
                               daemon=True)
         th.start()
@@ -410,34 +420,38 @@ class ServingEngine:
         """Really run one request through the active pipeline from
         ``start``; the measured timing occupies the stage workers on the
         stream clock.  Returns the completion time (None: outage drop)."""
+        with timing.span("engine.request", rid=rec.rid):
+            return self._serve(rec, inputs, start)
+
+    def _serve(self, rec: RequestRecord, inputs,
+               start: float) -> Optional[float]:
         entry = self.pool.snapshot_active()
         if entry is None:
             self.timeline.drop(rec, "outage")
             return None
-        _, timing = entry.pipeline.process(inputs)
+        _, req = entry.pipeline.process(inputs)
         if self.fault_plan is not None:
-            timing = self.fault_plan.perturb_timing(rec.rid, timing)
+            req = self.fault_plan.perturb_timing(rec.rid, req)
         sessions = self._live_sessions()
         if self._degraded:
             # edge-only: the cloud is unreachable, so any residual cloud
             # share executes on the edge hardware (scaled by how much
             # slower it is) and nothing crosses the link
             scale = getattr(entry.pipeline, "edge_scale", 1.0)
-            done = self.edge.occupy(start,
-                                    timing.t_edge + timing.t_cloud * scale)
+            done = self.edge.occupy(start, req.t_edge + req.t_cloud * scale)
             self.timeline.serve(rec, t_start=start, t_done=done,
                                 split=entry.split, degraded=True,
                                 sessions=sessions)
             self._inflight.append((done, rec))
             return done
-        if not math.isfinite(timing.t_transfer):
+        if not math.isfinite(req.t_transfer):
             # dead link without (or before) an open breaker: the request
             # cannot reach the cloud stage
             self.timeline.drop(rec, "link_down")
             return None
-        edge_end = self.edge.occupy(start, timing.t_edge)
-        cloud_start = max(edge_end + timing.t_transfer, self.cloud.busy_until)
-        done = self.cloud.occupy(cloud_start, timing.t_cloud)
+        edge_end = self.edge.occupy(start, req.t_edge)
+        cloud_start = max(edge_end + req.t_transfer, self.cloud.busy_until)
+        done = self.cloud.occupy(cloud_start, req.t_cloud)
         self.timeline.serve(rec, t_start=start, t_done=done, split=entry.split,
                             sessions=sessions)
         self._inflight.append((done, rec))
@@ -565,6 +579,10 @@ class ServingEngine:
         ``duration`` also bounds the control plane when there is no
         traffic (a control-only run).
         """
+        with timing.span("engine.run"):
+            return self._run(source, duration, clients)
+
+    def _run(self, source, duration, clients) -> ServiceTimeline:
         if self.warmup:
             entry = self.pool.snapshot_active()
             if entry is not None:
@@ -637,6 +655,7 @@ class ServingEngine:
                     k += 1
         while heap:
             t, _, _, kind, payload = heapq.heappop(heap)
+            timing.count("engine.events." + kind)
             self.clock.sleep_until(t)
             self._prune_inflight(t)
             if kind == "req":
@@ -651,7 +670,8 @@ class ServingEngine:
                 bw, lat = payload
                 self.set_network(NetworkModel(bw, latency_ms=lat))
             elif kind == "observe":
-                self.controller.observe_tick(t)
+                with timing.span("engine.observe"):
+                    self.controller.observe_tick(t)
             elif kind == "admit":
                 prompt, sid = payload
                 self.execute_admit(prompt, sid=sid)

@@ -43,7 +43,6 @@ the lock to commit).  See ``docs/serving.md`` for the full architecture.
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -53,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.core import timing
 from repro.core.concurrency import (RANK_SESSION_MANAGER, guarded_by,
                                     make_lock)
 from repro.core.hardware import CLOUD_SPEC
@@ -151,12 +151,13 @@ class SessionManager:
         """Per-slot decode positions, ``(num_slots,)`` int32 — dead slots
         sit at 0 and decode into their own (masked) row only."""
         with self._lock:
-            return jnp.asarray([s.pos for s in self._slots], jnp.int32)
+            pos = np.asarray([s.pos for s in self._slots], np.int32)
+        return timing.upload(pos)
 
     def next_token(self):
         """Greedy next token per slot (dead rows produce garbage tokens
         that only ever land in their own masked row)."""
-        return jnp.argmax(jnp.asarray(self.last_logits), -1)[:, None] \
+        return jnp.argmax(timing.upload(self.last_logits), -1)[:, None] \
             .astype(jnp.int32)
 
     def handoff_net(self, net: NetworkModel) -> NetworkModel:
@@ -179,9 +180,9 @@ class SessionManager:
         new batch, but tokens/bounds/logits commit per LIVE slot only —
         dead rows' garbage never reaches the bookkeeping buffers, so the
         zero-beyond-prefix invariant survives."""
-        tok = np.asarray(token)
-        b = np.asarray(bounds)
-        lg = np.asarray(logits)
+        tok = timing.fetch(token)
+        b = timing.fetch(bounds)
+        lg = timing.fetch(logits)
         with self._lock:
             self.cache.update(new_state)
             self.epoch += 1
@@ -211,36 +212,39 @@ class SessionManager:
         if not 0 < L <= self.max_seq:
             raise ValueError(f"prompt length {L} not in [1, {self.max_seq}]")
         r = self.runner
-        # resolve the compiled admission fn BEFORE taking our lock: the
-        # runner's cache lock ranks below ours (42 < 47)
-        admit_f = r.admit_fn()
-        tok = np.zeros((1, self.max_seq), np.int32)
-        tok[0, :L] = prompt
-        tok = jnp.asarray(tok)
-        logits, caches, bounds = admit_f(r.params, tok, jnp.int32(L))
-        jax.block_until_ready(logits)
-        if not self._calibrated:
-            # warm second run prices THIS HOST's recompute throughput for
-            # the hand-off planner, exactly like DecodeSession.prefill
-            t0 = time.perf_counter()    # nk: allow[NK02]: host calibration
-            jax.block_until_ready(admit_f(r.params, tok, jnp.int32(L))[0])
-            self._calibrate(time.perf_counter() - t0, L)  # nk: allow[NK02]
-        with self._lock:
-            j = self._find_slot()
-            slot = self._slots[j]
-            for k, v in caches.items():
-                self.cache[k] = self.cache[k].at[j].set(v[0])
-            self.bounds[:, j] = np.asarray(bounds)[:, 0]
-            self.tokens[j] = np.asarray(tok)[0]
-            self.last_logits[j] = np.asarray(logits)[0]
-            if sid is None:
-                sid = f"s{self._next_sid}"
-                self._next_sid += 1
-            self.epoch += 1
-            slot.sid, slot.live, slot.pos, slot.epoch = sid, True, L, \
-                self.epoch
-            self._touch(slot)
-            self._evict_to_budget(keep=j)
+        with timing.span("sessions.admit", sid=sid):
+            with timing.span("admit.prefill"):
+                # resolve the compiled admission fn BEFORE taking our lock:
+                # the runner's cache lock ranks below ours (42 < 47)
+                admit_f = r.admit_fn()
+                tok = np.zeros((1, self.max_seq), np.int32)
+                tok[0, :L] = prompt
+                tok = timing.upload(tok)
+                logits, caches, bounds = admit_f(r.params, tok, jnp.int32(L))
+                timing.block(logits)
+            if not self._calibrated:
+                # warm second run prices THIS HOST's recompute throughput
+                # for the hand-off planner, exactly like
+                # DecodeSession.prefill
+                with timing.span("admit.calibrate", timed=True) as m:
+                    timing.block(admit_f(r.params, tok, jnp.int32(L))[0])
+                self._calibrate(m.wall, L)
+            with timing.span("admit.place"), self._lock:
+                j = self._find_slot()
+                slot = self._slots[j]
+                for k, v in caches.items():
+                    self.cache[k] = self.cache[k].at[j].set(v[0])
+                self.bounds[:, j] = timing.fetch(bounds)[:, 0]
+                self.tokens[j] = timing.fetch(tok)[0]
+                self.last_logits[j] = timing.fetch(logits)[0]
+                if sid is None:
+                    sid = f"s{self._next_sid}"
+                    self._next_sid += 1
+                self.epoch += 1
+                slot.sid, slot.live, slot.pos, slot.epoch = sid, True, L, \
+                    self.epoch
+                self._touch(slot)
+                self._evict_to_budget(keep=j)
         return sid
 
     def _calibrate(self, wall: float, toks: int) -> None:
@@ -303,7 +307,7 @@ class SessionManager:
         ``readmit``.  The parked payload uses the same serialized
         ``(dtype, shape, bytes)`` entries as ``export_layers``, so the
         round trip exercises the hand-off representation."""
-        with self._lock:
+        with timing.span("sessions.evict", sid=sid), self._lock:
             self._park(self._slot_index(sid))
 
     def _slot_index(self, sid: str) -> int:    # holds: _lock
@@ -313,11 +317,15 @@ class SessionManager:
         raise KeyError(f"no live session {sid!r}")
 
     def _park(self, j: int) -> None:    # holds: _lock
+        with timing.span("sessions.park", sid=self._slots[j].sid):
+            self._park_slot(j)
+
+    def _park_slot(self, j: int) -> None:    # holds: _lock
         slot = self._slots[j]
         state: Dict[str, tuple] = {}
         for unit in self.runner.units:
             for k in _unit_state_keys(self.cfg, unit):
-                arr = np.asarray(self.cache[k][j])
+                arr = timing.fetch(self.cache[k][j])
                 if k[0] in ("k", "v", "a"):      # row KV: (KH, S, hd)
                     arr = arr[:, :slot.pos]
                 state[k] = (str(arr.dtype), arr.shape, arr.tobytes())
@@ -351,7 +359,7 @@ class SessionManager:
                     full = np.zeros(self.cache[k].shape[1:], arr.dtype)
                     full[:, :arr.shape[1]] = arr
                     arr = full
-                self.cache[k] = self.cache[k].at[j].set(jnp.asarray(arr))
+                self.cache[k] = self.cache[k].at[j].set(timing.upload(arr))
             self.tokens[j, :pos] = parked["tokens"]
             self.bounds[:, j, :pos] = parked["bounds"]
             self.last_logits[j] = parked["logits"]
@@ -398,7 +406,7 @@ class SessionManager:
             pos = max((s.pos for s in self._slots if s.live), default=0)
             for unit in self.runner.units[u0:u1]:
                 for k in _unit_state_keys(self.cfg, unit):
-                    arr = np.asarray(self.cache[k])
+                    arr = timing.fetch(self.cache[k])
                     if k[0] in ("k", "v", "a"):
                         arr = arr[:, :, :pos]
                     buf = arr.tobytes()
@@ -444,9 +452,9 @@ class SessionManager:
                 if k[0] in ("k", "v", "a"):
                     full = np.zeros(self.cache[k].shape, arr.dtype)
                     full[:, :, :arr.shape[2]] = arr
-                    self.cache[k] = jnp.asarray(full)
+                    self.cache[k] = timing.upload(full)
                 else:
-                    self.cache[k] = jnp.asarray(arr)
+                    self.cache[k] = timing.upload(arr)
 
     def recompute_layers(self, lo: int, hi: int) -> None:
         """Rebuild layers [lo, hi) for EVERY slot from the per-slot
@@ -460,10 +468,11 @@ class SessionManager:
         r = self.runner
         fn = r.recompute_fn(u0, u1)          # runner lock first (42 < 47)
         with self._lock:
-            x0 = jnp.asarray(self.bounds[u0])            # (B, max_seq, D)
-            lengths = jnp.asarray([s.pos for s in self._slots], jnp.int32)
+            x0 = timing.upload(self.bounds[u0])          # (B, max_seq, D)
+            lengths = timing.upload(
+                np.asarray([s.pos for s in self._slots], np.int32))
         caches = fn(r.params, x0, lengths)
-        jax.block_until_ready(caches)
+        timing.block(caches)
         with self._lock:
             self.cache.update(caches)
 
@@ -494,7 +503,7 @@ class SessionManager:
         logits, new, b = self._step_fn(r.params, token, self.subset(0, U),
                                        self.step_pos())
         self.commit_step(token, new, b, logits)
-        return np.asarray(token)
+        return timing.fetch(token)
 
     # -- test/benchmark support -------------------------------------------
     def snapshot(self) -> dict:

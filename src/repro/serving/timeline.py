@@ -18,6 +18,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.core import timing
+
 
 @dataclass
 class RequestRecord:
@@ -127,6 +129,7 @@ class ServiceTimeline:
 
     def drop(self, rec: RequestRecord, reason: str) -> None:
         rec.drop_reason = reason
+        timing.count("engine.drops." + reason)
 
     def serve(self, rec: RequestRecord, *, t_start: float, t_done: float,
               split: int, degraded: bool = False,

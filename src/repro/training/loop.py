@@ -1,7 +1,6 @@
 """Training loop with checkpointing — the train-side e2e driver."""
 from __future__ import annotations
 
-import time
 from typing import Callable, Dict, Optional
 
 import jax
@@ -9,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.core import timing
 from repro.data import SyntheticTokens
 from repro.models import transformer as T
 from repro.optim import adamw, cosine_schedule
@@ -32,10 +32,10 @@ def train(cfg: ArchConfig, *, steps: int, batch: int, seq: int,
     hist = {"loss": [], "step_time": []}
     for i in range(steps):
         b = {k: jnp.asarray(v) for k, v in next(it).items()}
-        t0 = time.perf_counter()
-        params, opt_state, metrics = jstep(params, opt_state, b)
-        loss = float(metrics["loss"])
-        dt = time.perf_counter() - t0
+        with timing.measure() as m:
+            params, opt_state, metrics = jstep(params, opt_state, b)
+            loss = float(metrics["loss"])
+        dt = m.wall
         hist["loss"].append(loss)
         hist["step_time"].append(dt)
         if log_every and i % log_every == 0:

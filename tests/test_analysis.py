@@ -233,6 +233,36 @@ def step(x):
     assert len(fs) == 1 and "random.random" in fs[0].message
 
 
+def test_nk03_flags_program_spans_and_counters():
+    src = '''
+import jax
+from jax.experimental import pallas as pl
+from repro.core import timing
+from repro.core.timing import count
+
+@jax.jit
+def step(x):
+    with timing.span("step"):
+        return x * 2
+
+def kernel(x_ref, o_ref):
+    count("host_sync")
+    o_ref[...] = x_ref[...]
+
+def call(x, shape):
+    return pl.pallas_call(kernel, out_shape=shape)(x)
+
+def host(x):
+    with timing.span("step"):
+        timing.count("host_sync")
+        return step(x)
+'''
+    fs = findings_for(TracingHygieneRule(), {"src/k.py": src})
+    assert sorted(f.message.split("(")[0] for f in fs) == \
+        ["count", "timing.span"]
+    assert all("trace time" in f.message for f in fs)
+
+
 def test_nk03_computed_static_argnums():
     src = '''
 import jax

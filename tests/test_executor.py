@@ -207,7 +207,6 @@ def test_switch_a_returns_after_pointer_swap(setup):
     assert out.shape[-1] == cfg.vocab_size
     mgr.drain()
     assert rep.t_background_wall > 0.0          # filled in by the worker
-    assert rep.background_cost == rep.t_background_wall
     assert mgr.standby is not None and mgr.standby.ready
     assert mgr.standby.split == 1               # rebuilt for the old config
 
