@@ -197,11 +197,14 @@ class StatefulStageRunner:
     keeps the XLA reference ops; ``"auto"`` resolves ONCE at construction
     to kernel on TPU and reference on CPU (where the Pallas kernels only
     run in interpret mode — correct, so tests pin ``"kernel"`` for
-    parity, but orders slower than XLA).  ``rolled`` collapses each unit
-    range into a ``lax.scan`` over the stacked per-layer weights instead
-    of an unrolled Python loop, shrinking the HLO and the per-range AOT
-    compile wall; ``rolled=False`` keeps the unrolled trace for parity
-    tests and the decode microbenchmark's A/B."""
+    parity, but orders slower than XLA).  ``rolled`` collapses each
+    full-sequence range (prefill, recompute) and each decode span of
+    mamba layers into a ``lax.scan`` over the stacked per-layer weights
+    instead of an unrolled Python loop, shrinking the HLO and the
+    per-range AOT compile wall; attention-layer decode ranges read each
+    layer through a static index either way.  ``rolled=False`` keeps
+    the unrolled trace for parity tests and the decode
+    microbenchmark's A/B."""
 
     def __init__(self, cfg: ArchConfig, params, *, max_seq: int = 128,
                  attn_impl: str = "chunked", decode_impl: str = "auto",
@@ -347,8 +350,14 @@ class StatefulStageRunner:
     # scans ONE layer body over the stacked per-layer weights and caches
     # (params["layers"] is already stacked on a leading L axis).  Hybrid
     # ranges roll per homogeneous segment: runs of mamba layers scan,
-    # each shared-attn application stays a single unrolled unit.  Both
-    # traces honour the same (x, new_state, bounds) contract, so the
+    # each shared-attn application stays a single unrolled unit.
+    # Attention-layer DECODE ranges never scan: at the TPU's default
+    # precision XLA hoists the scanned body's f32->bf16 operand convert
+    # out of the loop and materialises a bf16 copy of the whole range's
+    # weights on every token (2.97 GB of temporaries for 18 qwen2.5-3b
+    # layers), while a static per-layer index lets each dot read its
+    # f32 weights straight from the stacked parameter.  Both traces
+    # honour the same (x, new_state, bounds) contract, so the
     # session/hand-off machinery never sees the difference.
 
     def _segments(self, u0: int, u1: int) -> List[Tuple[str, int, int]]:
@@ -363,43 +372,6 @@ class StatefulStageRunner:
             else:
                 segs.append((kind, idx, idx + 1))
         return segs
-
-    def _decode_attn_span(self, params, x, cache, new, pos, rope, lo, hi):
-        """Scan the one-token attention-layer body over layers [lo, hi).
-        Per-layer KV caches ride as scan xs/ys (layer caches are
-        independent), so only ``x`` is carried."""
-        cfg = self.cfg
-        B = x.shape[0]
-        cos, sin = rope
-        lp = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-        k_all = jnp.stack([cache[f"k{i}"] for i in range(lo, hi)])
-        v_all = jnp.stack([cache[f"v{i}"] for i in range(lo, hi)])
-
-        def body(x, xs):
-            p, kc, vc = xs
-            bound = x
-            h = T._apply_norm(cfg, p["ln1"], x)
-            q, k, v = T._project_qkv(cfg, p["attn"], h)
-            q = Lyr.apply_rope(q, cos, sin)
-            k = Lyr.apply_rope(k, cos, sin)
-            kc = self._cache_write(
-                kc, k.transpose(0, 2, 1, 3).astype(kc.dtype), pos)
-            vc = self._cache_write(
-                vc, v.transpose(0, 2, 1, 3).astype(vc.dtype), pos)
-            att = self._attend(q, kc, vc, pos)
-            x = x + att.reshape(B, 1, -1) @ p["attn"]["wo"]
-            h2 = T._apply_norm(cfg, p["ln2"], x)
-            if "moe" in p:
-                ff, _ = Lyr.moe_layer(p["moe"], h2, top_k=cfg.moe.top_k,
-                                      capacity_factor=cfg.moe.capacity_factor)
-            else:
-                ff = Lyr.mlp(p["mlp"], h2, gated=cfg.gated_mlp)
-            return x + ff, (bound, kc, vc)
-
-        x, (bounds, k_new, v_new) = jax.lax.scan(body, x, (lp, k_all, v_all))
-        for j, i in enumerate(range(lo, hi)):
-            new[f"k{i}"], new[f"v{i}"] = k_new[j], v_new[j]
-        return x, bounds
 
     def _decode_ssm_span(self, params, x, cache, new, pos, lo, hi):
         """Scan the one-token mamba-layer body over layers [lo, hi)."""
@@ -431,17 +403,12 @@ class StatefulStageRunner:
         def fn(params, x, cache, pos):
             new: Dict[str, Any] = {}
             parts = []
-            rope = self._decode_rope(pos)
             for kind, lo, hi in segs:
-                if kind == "app":
-                    for g in range(lo, hi):
+                if kind == "app" or cfg.family in _ATTN_FAMILIES:
+                    for i in range(lo, hi):
                         parts.append(x[None])
-                        x = self._decode_unit(params, ("app", g), x, cache,
+                        x = self._decode_unit(params, (kind, i), x, cache,
                                               new, pos)
-                elif cfg.family in _ATTN_FAMILIES:
-                    x, b = self._decode_attn_span(params, x, cache, new,
-                                                  pos, rope, lo, hi)
-                    parts.append(b)
                 else:
                     x, b = self._decode_ssm_span(params, x, cache, new,
                                                  pos, lo, hi)

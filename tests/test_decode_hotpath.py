@@ -2,7 +2,9 @@
 
 The serving decode path has two orthogonal knobs on
 ``StatefulStageRunner`` — ``rolled`` (lax.scan over stacked per-layer
-weights vs the unrolled Python-loop trace) and ``decode_impl``
+weights for mamba decode spans and full-sequence ranges vs the unrolled
+Python-loop trace; attention decode ranges read each layer through a
+static index either way) and ``decode_impl``
 (``flash_decode``/``mamba_scan``/``ssd_scan`` Pallas kernels vs the XLA
 reference ops).  Every combination must produce the same logits AND the
 same exported hand-off state layout, for all four families (plus a GQA
