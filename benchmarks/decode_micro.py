@@ -78,7 +78,8 @@ def _variant(cfg, params, sess, U, x, pos_val, *, decode_impl, rolled,
                             decode_impl=decode_impl, rolled=rolled)
     cache = sess.subset(0, U)
     pos = jnp.int32(pos_val)
-    avals = (jax.ShapeDtypeStruct(x.shape, x.dtype), cache,
+    avals = (jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          x), cache,
              jax.ShapeDtypeStruct((), jnp.int32))
 
     colds = []
@@ -100,7 +101,7 @@ def _variant(cfg, params, sess, U, x, pos_val, *, decode_impl, rolled,
     jax.block_until_ready(out[0])
     wall = time.perf_counter() - t0
 
-    B = x.shape[0]
+    B = r.hidden(x).shape[0]
     tokens_per_s = B * steps / wall / jax.device_count()
     roof = kernel_roofline(f"decode_{decode_impl}", wall_s=wall / steps,
                            cost=executable_cost(dec))
@@ -133,7 +134,7 @@ def bench_cell(family, *, seq, batch, steps, build_reps, pin_kernel):
     sess = DecodeSession(r0)
     sess.prefill(toks)
     U = len(r0.units)
-    x = params["embed"][jnp.asarray(sess.next_token(), jnp.int32)]
+    x = r0.stream(params["embed"][jnp.asarray(sess.next_token(), jnp.int32)])
 
     cell = {
         "ref": _variant(cfg, params, sess, U, x, sess.pos,

@@ -32,6 +32,8 @@ class SSMConfig:
     expand: int = 2
     head_dim: int = 64          # mamba2 only
     dt_rank: int = 0            # mamba1: ceil(d_model/16) when 0
+    n_groups: int = 1           # mamba2: B/C groups, each shared by a block
+                                # of consecutive heads
 
 
 @dataclass(frozen=True)
@@ -58,7 +60,14 @@ class ArchConfig:
     long_context_window: Optional[int] = None  # swa-variant used only for long_500k
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    hybrid_period: int = 0      # zamba2: shared attn block applied every N layers
+    # zamba2: a shared transformer block is applied before each layer of
+    # ``hybrid_layer_ids`` (the i-th application uses block i mod
+    # ``num_mem_blocks``, with its own MLP adapter of rank
+    # ``adapter_rank`` and its own output linear); its attention runs
+    # over concat(x, token embedding), width num_heads * head_dim
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     encoder: Optional[EncoderConfig] = None
     frontend: Optional[str] = None  # 'vision' | 'audio' (stubbed; embeddings provided)
     frontend_tokens: int = 0        # patch/frame embeddings prepended (vlm)
@@ -82,6 +91,13 @@ class ArchConfig:
         return self.ssm.expand * self.d_model
 
     @property
+    def app_layers(self) -> Tuple[int, ...]:
+        """Hybrid: the layers (below ``num_layers``) that a shared-block
+        application precedes, in order; application ``g`` sits before
+        layer ``app_layers[g]``."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.num_layers)
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -98,12 +114,14 @@ class ArchConfig:
         if self.family == "ssm":
             return (self.ssm.kind,) * self.num_layers
         if self.family == "hybrid":
-            # mamba2 backbone; shared attn applied every `hybrid_period` layers
+            # mamba2 backbone; shared blocks run inside the app_layers
             return tuple("mamba2" for _ in range(self.num_layers))
         return ("attn",) * self.num_layers
 
     def reduced(self) -> "ArchConfig":
-        """CPU smoke variant of the same family (2 layers, d_model<=512, <=4 experts)."""
+        """CPU smoke variant of the same family (2 layers, d_model<=512,
+        <=4 experts; hybrid: 4 layers, so that two shared blocks apply in
+        turn, before layers 1 and 3)."""
         heads = min(self.num_heads, 4) or 4
         kv = max(1, heads * self.num_kv_heads // max(self.num_heads, 1)) if self.num_kv_heads else 0
         moe = None
@@ -118,17 +136,20 @@ class ArchConfig:
         if self.ssm is not None:
             ssm = dataclasses.replace(self.ssm, d_state=min(self.ssm.d_state, 16),
                                       head_dim=32, dt_rank=16)
+        hybrid = dict(num_layers=2, head_dim=256 // heads if heads else 0)
+        if self.family == "hybrid":
+            # the attention runs over concat(x, x0): 4 heads of 2 * 256 / 4
+            hybrid = dict(num_layers=4, hybrid_layer_ids=(1, 3),
+                          head_dim=128, adapter_rank=8)
         enc = None
         if self.encoder is not None:
             enc = EncoderConfig(num_layers=2, context_len=16)
         return dataclasses.replace(
-            self, name=self.name + "-smoke", num_layers=2, d_model=256,
-            num_heads=heads, num_kv_heads=kv, head_dim=256 // heads if heads else 0,
-            d_ff=512, vocab_size=512, moe=moe, ssm=ssm, encoder=enc,
-            hybrid_period=2 if self.hybrid_period else 0,
+            self, name=self.name + "-smoke", d_model=256,
+            num_heads=heads, num_kv_heads=kv, d_ff=512, vocab_size=512, moe=moe, ssm=ssm, encoder=enc,
             sliding_window=64 if self.sliding_window else None,
             long_context_window=64 if self.long_context_window else None,
-            frontend_tokens=8 if self.frontend_tokens else 0)
+            frontend_tokens=8 if self.frontend_tokens else 0, **hybrid)
 
     # ---- analytics -----------------------------------------------------
     def param_count(self) -> int:
@@ -149,8 +170,9 @@ class ArchConfig:
                 total += self._mamba1_params()
             elif k == "mamba2":
                 total += self._mamba2_params()
-        if self.family == "hybrid" and self.hybrid_period:
-            total += self._attn_params() + self._ffn_params()  # one shared block
+        if self.family == "hybrid":
+            total += self.num_mem_blocks * self._shared_block_params() \
+                + len(self.app_layers) * self._app_params()
         if self.encoder is not None:
             total += self.encoder.num_layers * (
                 self._attn_params() + self._ffn_params())
@@ -191,8 +213,20 @@ class ArchConfig:
         d, di = self.d_model, self.d_inner
         s = self.ssm
         nheads = di // s.head_dim
-        return (d * (2 * di + 2 * s.d_state + nheads) + di * s.d_conv
-                + nheads + nheads + di + di * d)
+        conv = di + 2 * s.n_groups * s.d_state
+        return (d * (di + conv + nheads) + conv * (s.d_conv + 1)
+                + 3 * nheads + di + di * d + d)
+
+    def _shared_block_params(self) -> int:
+        """Hybrid: one shared block (norms over 2d and d, attention from
+        2d wide to d, gated MLP)."""
+        d, w = self.d_model, self.num_heads * self.head_dim
+        return 2 * d + 3 * 2 * d * w + w * d + d + 3 * d * self.d_ff
+
+    def _app_params(self) -> int:
+        """Hybrid: one application's MLP adapter and output linear."""
+        d, r = self.d_model, self.adapter_rank
+        return d * r + r * 2 * self.d_ff + d * d
 
 
 @dataclass(frozen=True)

@@ -24,7 +24,8 @@ from repro.core.profiler import (ModelProfile, UnitProfile, profile_cnn,
                                  profile_transformer)
 from repro.core.stages import StageRunner
 from repro.core.state_handoff import (HandoffPlan, HandoffSplitClamped,
-                                      per_layer_state_bytes, plan_handoff)
+                                      per_layer_state_bytes, plan_handoff,
+                                      range_state_bytes)
 from repro.core.stateful import (DecodeSession, HandoffCorrupted,
                                  HandoffIntegrityWarning, HandoffReport,
                                  StatefulEdgeCloudPipeline,

@@ -197,8 +197,14 @@ def _layer_flops(cfg: ArchConfig, kind: str, tokens: int, seq: int) -> float:
     if kind == "mamba2":
         di, s = cfg.d_inner, cfg.ssm
         H = di // s.head_dim
-        return 2 * tokens * d * (2 * di + 2 * s.d_state + H) \
+        return 2 * tokens * d * (2 * di + 2 * s.n_groups * s.d_state + H) \
             + 2 * tokens * di * d + 6 * tokens * di * s.d_state
+    if kind == "shared":            # one application of a hybrid's block
+        w, F, r = cfg.num_heads * cfg.head_dim, cfg.d_ff, cfg.adapter_rank
+        ctx = min(seq, cfg.sliding_window or seq)
+        return 2 * tokens * (3 * 2 * d * w + w * d + 3 * d * F
+                             + d * r + r * 2 * F + d * d) \
+            + 4 * tokens * ctx * w
     raise ValueError(kind)
 
 
@@ -216,23 +222,23 @@ def profile_transformer(cfg: ArchConfig, *, seq: int, batch: int = 1,
     """
     tokens = batch * seq
     bbytes = batch * seq * cfg.d_model * act_bytes
-    units = [UnitProfile("embed", 0.0, 0.0, bbytes, 0.0)]
-    kinds = list(cfg.layer_kinds())
-    if cfg.family == "hybrid" and cfg.hybrid_period:
-        # insert the shared attn applications as units
-        out = []
-        for i, k in enumerate(kinds):
-            out.append(k)
-            if (i + 1) % cfg.hybrid_period == 0:
-                out.append("attn")
-        kinds = out
-    for i, kind in enumerate(kinds):
+    apps = cfg.app_layers if cfg.family == "hybrid" else ()
+
+    def out_bytes(i):
+        # the hybrid's x0 stream crosses beside x while an application
+        # lies past the boundary after unit i (i = -1: the embedding)
+        return bbytes * (2 if any(a > i for a in apps) else 1)
+
+    units = [UnitProfile("embed", 0.0, 0.0, out_bytes(-1), 0.0)]
+    for i, kind in enumerate(cfg.layer_kinds()):
         fl = _layer_flops(cfg, kind, tokens, seq)
+        if i in apps:               # the application runs inside layer i
+            fl += _layer_flops(cfg, "shared", tokens, seq)
         units.append(UnitProfile(
             f"{kind}{i}",
             fl / (edge.flops * edge.mfu),
             fl / (cloud.flops * cloud.mfu),
-            bbytes, fl))
+            out_bytes(i), fl))
     head_fl = 2 * tokens * cfg.d_model * cfg.vocab_size
     units.append(UnitProfile("head", head_fl / (edge.flops * edge.mfu),
                              head_fl / (cloud.flops * cloud.mfu), 0, head_fl))
@@ -252,7 +258,7 @@ def calibrate_decode(profile: ModelProfile, timings: Sequence, *,
     ``RequestTiming``s that ``StatefulEdgeCloudPipeline.process``
     returns), taken at a known ``split`` — the same split-after-unit
     index ``latency``/``optimal_split`` use (for a stateful pipeline at
-    layer split ``s`` that is ``stateful.unit_index_of_split(cfg, s)``).
+    layer split ``s`` that is ``s``).
     The medians fix the absolute scale of the edge and cloud sides; the
     analytic profile keeps fixing the *relative* per-layer shape.  This
     is what lets ``optimal_split`` price the kernel-routed decode path

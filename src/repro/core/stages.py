@@ -193,7 +193,7 @@ class StageRunner(_CompiledStageCache):
                 enc = T.encode_audio(cfg, params, state["frames"],
                                      attn_impl=self.attn_impl, remat=False)
                 return {"h": x, "enc": enc}
-            return {"h": x}
+            return {"h": x, "x0": x} if cfg.family == "hybrid" else {"h": x}
         if i == self.num_units - 1:
             x = T._apply_norm(cfg, params["final_norm"], state["h"])
             logits = (x @ T.lm_head_weights(cfg, params)).astype(jnp.float32)
@@ -217,13 +217,14 @@ class StageRunner(_CompiledStageCache):
             y, _ = SSM.mamba1_block(lp["mamba"], h, cfg=cfg)
             x = x + y
         elif fam == "hybrid":
-            lp = _layer_at(params, li)
-            h = T._apply_norm(cfg, lp["ln"], x)
-            y, _ = SSM.mamba2_block(lp["mamba"], h, cfg=cfg)
-            x = x + y
-            if cfg.hybrid_period and (li + 1) % cfg.hybrid_period == 0:
-                x, _, _ = T.attn_block_full(cfg, params["shared"], x, rope_cs,
-                                            impl=self.attn_impl, window=window)
+            t = None
+            if li in cfg.app_layers:
+                def attend(q, k, v):
+                    return Lyr.attention(q, k, v, causal=True, window=window,
+                                         impl=self.attn_impl), None
+                t, _ = T.hybrid_block(cfg, params, cfg.app_layers.index(li),
+                                      x, state["x0"], rope_cs, attend)
+            x, _ = T.hybrid_layer(cfg, _layer_at(params, li), x, t)
         else:
             raise ValueError(fam)
         out = dict(state)
@@ -267,6 +268,9 @@ class StageRunner(_CompiledStageCache):
         """Bytes crossing the link for a split after unit `split`."""
         cfg = self.cfg
         n = batch * seq * cfg.d_model * act_bytes
+        if cfg.family == "hybrid" and any(i >= split
+                                          for i in cfg.app_layers):
+            n *= 2                  # x0 beside x while an application waits
         if cfg.family == "audio":
             n += batch * cfg.encoder.context_len * cfg.d_model * act_bytes
         return n

@@ -13,7 +13,7 @@ The ``batch`` axis prices multi-session slot pools: a pool serving N
 concurrent sessions hands off N rows of every moved layer's state in one
 batched payload, so both arms scale linearly in live-slot count —
 ``SessionManager.slot_state_bytes`` charges admission/eviction against
-its memory budget through the same ``per_layer_state_bytes``.
+its memory budget through the same ``range_state_bytes``.
 """
 from __future__ import annotations
 
@@ -38,19 +38,32 @@ def per_layer_state_bytes(cfg: ArchConfig, *, seq_len: int, batch: int = 1,
         ssm = cfg.d_inner * s.d_state * 4                    # f32 state
         return batch * (conv + ssm)
     if cfg.family == "hybrid":
-        s = cfg.ssm
-        conv = (s.d_conv - 1) * (cfg.d_inner + 2 * s.d_state) * act_bytes
-        ssm = cfg.d_inner * s.d_state * 4
-        mamba = batch * (conv + ssm)
-        # shared attention KV amortised over the layers of one period
-        window = cfg.sliding_window or seq_len
-        kv = batch * 2 * cfg.num_kv_heads * cfg.head_dim \
-            * min(seq_len, window) * act_bytes / max(cfg.hybrid_period, 1)
-        return mamba + kv
+        # the applications' KV spread over the layers
+        return range_state_bytes(cfg, 0, cfg.num_layers, seq_len=seq_len,
+                                 batch=batch, act_bytes=act_bytes) \
+            / cfg.num_layers
     # attention families
     window = cfg.sliding_window or seq_len
     return batch * 2 * cfg.num_kv_heads * cfg.head_dim \
         * min(seq_len, window) * act_bytes
+
+
+def range_state_bytes(cfg: ArchConfig, lo: int, hi: int, *, seq_len: int,
+                      batch: int = 1, act_bytes: int = 2) -> float:
+    """Decode-state bytes of layers [lo, hi); a hybrid layer carries the
+    KV of the application before it besides its conv and SSM state."""
+    if cfg.family != "hybrid":
+        return (hi - lo) * per_layer_state_bytes(
+            cfg, seq_len=seq_len, batch=batch, act_bytes=act_bytes)
+    s = cfg.ssm
+    conv = (s.d_conv - 1) * (cfg.d_inner + 2 * s.n_groups * s.d_state) \
+        * act_bytes
+    ssm = cfg.d_inner * s.d_state * 4                        # f32 state
+    window = cfg.sliding_window or seq_len
+    kv = 2 * cfg.num_kv_heads * cfg.head_dim * min(seq_len, window) \
+        * act_bytes
+    apps = sum(lo <= i < hi for i in cfg.app_layers)
+    return batch * ((hi - lo) * (conv + ssm) + apps * kv)
 
 
 @dataclass
@@ -90,15 +103,18 @@ def plan_handoff(cfg: ArchConfig, *, old_split: int, new_split: int,
             HandoffSplitClamped)
     old_split, new_split = clamped_old, clamped_new
     moved = abs(new_split - old_split)
-    per_layer = per_layer_state_bytes(cfg, seq_len=seq_len, batch=batch,
-                                      act_bytes=act_bytes)
-    moved_bytes = int(moved * per_layer)
+    lo, hi = min(old_split, new_split), max(old_split, new_split)
+    moved_bytes = int(range_state_bytes(cfg, lo, hi, seq_len=seq_len,
+                                        batch=batch, act_bytes=act_bytes))
     t_transfer = net.transfer_time(moved_bytes) if moved else 0.0
     # recompute: re-run the moved layers over the full context on the target
     from repro.core.profiler import _layer_flops
     flops = sum(
         _layer_flops(cfg, kinds[i], tokens=batch * seq_len, seq=seq_len)
-        for i in range(min(old_split, new_split), max(old_split, new_split)))
+        for i in range(lo, hi))
+    if cfg.family == "hybrid":
+        flops += sum(lo <= i < hi for i in cfg.app_layers) * _layer_flops(
+            cfg, "shared", tokens=batch * seq_len, seq=seq_len)
     t_recompute = flops / (target.flops * target.mfu) if moved else 0.0
     best = "transfer" if t_transfer <= t_recompute else "recompute"
     return HandoffPlan(moved, moved_bytes, t_transfer, t_recompute, best)

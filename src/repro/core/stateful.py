@@ -22,17 +22,17 @@ head with the cloud stage):
 
 ``StatefulStageRunner``
     Compiles decode-step and full-sequence executables for contiguous
-    *unit* ranges (a unit is a decoder layer, or — for the hybrid
-    family — one application of the shared attention block).  AOT
+    layer ranges (a hybrid layer carries the shared-block application
+    that precedes it, so the two never split).  AOT
     executables are cached per ``(range, avals)`` exactly like
     ``StageRunner``'s, with ``fresh=True`` keeping "new container"
     retrace semantics.
 
 ``DecodeSession``
-    The per-stream decode state: token history, one state entry per
-    unit (``k{i}``/``v{i}`` heads-major KV, ``conv{i}``/``ssm{i}``
-    recurrent state, ``ak{g}``/``av{g}`` shared-attn KV), the per-unit
-    boundary activations that make targeted recompute possible, and a
+    The per-stream decode state: token history, the state entries of
+    every layer (``state_keys``: ``k{i}``/``v{i}`` heads-major KV,
+    ``conv{i}``/``ssm{i}`` recurrent state, ``ak{g}``/``av{g}`` the KV
+    of hybrid application ``g``), the per-layer boundary activations that make targeted recompute possible, and a
     monotonically increasing **state epoch** — the version number the
     pool uses to decide whether a standby's view of the context can be
     trusted.  ``export_layers``/``import_layers``/``recompute_layers``
@@ -136,37 +136,99 @@ def payload_checksum(payload: Dict[Any, tuple]) -> int:
 # unit layout
 # ---------------------------------------------------------------------------
 
-def unit_list(cfg: ArchConfig) -> List[Tuple[str, int]]:
-    """Execution-ordered state units: ``("layer", i)`` per decoder layer,
-    plus ``("app", g)`` after every ``hybrid_period``-th hybrid layer."""
-    if cfg.family not in _SUPPORTED:
-        raise ValueError(f"stateful serving unsupported for {cfg.family!r}")
-    units: List[Tuple[str, int]] = []
-    for i in range(cfg.num_layers):
-        units.append(("layer", i))
-        if cfg.family == "hybrid" and cfg.hybrid_period \
-                and (i + 1) % cfg.hybrid_period == 0:
-            units.append(("app", (i + 1) // cfg.hybrid_period - 1))
-    return units
-
-
-def unit_index_of_split(cfg: ArchConfig, split: int) -> int:
-    """Units on the edge for a split of ``split`` LAYERS: layers
-    ``[0, split)`` plus any shared-attn application firing inside them."""
-    split = min(max(split, 0), cfg.num_layers)
-    idx = split
-    if cfg.family == "hybrid" and cfg.hybrid_period:
-        idx += split // cfg.hybrid_period
-    return idx
-
-
-def _unit_state_keys(cfg: ArchConfig, unit: Tuple[str, int]) -> Tuple[str, ...]:
-    kind, idx = unit
-    if kind == "app":
-        return (f"ak{idx}", f"av{idx}")
+def state_keys(cfg: ArchConfig, i: int) -> Tuple[str, ...]:
+    """The decode-state entries of layer ``i``: ``k{i}``/``v{i}`` for an
+    attention layer, ``conv{i}``/``ssm{i}`` for a Mamba layer, and for a
+    hybrid layer preceded by application ``g`` also that application's
+    KV, ``ak{g}``/``av{g}`` (the layer and its application never split)."""
     if cfg.family in _ATTN_FAMILIES:
-        return (f"k{idx}", f"v{idx}")
-    return (f"conv{idx}", f"ssm{idx}")
+        return (f"k{i}", f"v{i}")
+    if cfg.family == "hybrid" and i in cfg.app_layers:
+        g = cfg.app_layers.index(i)
+        return (f"conv{i}", f"ssm{i}", f"ak{g}", f"av{g}")
+    return (f"conv{i}", f"ssm{i}")
+
+
+def is_kv(key: str) -> bool:
+    """Whether a state entry is attention KV (sliced to the live context
+    when serialized) rather than fixed-size SSM/conv state."""
+    return key[0] in ("k", "v", "a")
+
+
+def export_state(cache: Dict[str, Any], keys, pos: int
+                 ) -> Tuple[Dict[str, tuple], int]:
+    """Serialize the entries ``keys`` of ``cache``: KV sliced to the first
+    ``pos`` positions, SSM/conv state whole, as ``(dtype, shape, bytes)``.
+    The SSM/conv state and the KV are each one ``handoff.export.<kind>``
+    span with a ``handoff_bytes.<kind>`` counter.  Returns (payload,
+    nbytes)."""
+    payload: Dict[str, tuple] = {}
+    nbytes = 0
+    for kind in ("ssm", "kv"):
+        ks = [k for k in keys if is_kv(k) == (kind == "kv")]
+        if not ks:
+            continue
+        with timing.span(f"handoff.export.{kind}"):
+            n = 0
+            for k in ks:
+                arr = timing.fetch(cache[k])
+                if kind == "kv":                 # valid region only
+                    arr = arr[:, :, :pos]
+                buf = arr.tobytes()
+                payload[k] = (str(arr.dtype), arr.shape, buf)
+                n += len(buf)
+            timing.count(f"handoff_bytes.{kind}", n)
+        nbytes += n
+    return payload, nbytes
+
+
+def count_state(entries: Dict[str, Any], pos: int) -> None:
+    """Count the bytes of state ``entries`` by kind, as ``export_state``
+    would serialize them (KV to ``pos`` positions), into the
+    ``handoff_bytes.<kind>`` counters: the recompute arm's state rebuilt
+    on the target."""
+    for k, a in entries.items():
+        n = a.size * a.dtype.itemsize
+        if is_kv(k):
+            n = n * pos // a.shape[2]
+        timing.count("handoff_bytes.kv" if is_kv(k) else "handoff_bytes.ssm",
+                      int(n))
+
+
+def decode_payload(payload: Dict[str, tuple]) -> Dict[str, np.ndarray]:
+    """The arrays of a payload (envelope excluded), fully decoded before
+    anything is committed; raises ``HandoffCorrupted`` on a bad entry."""
+    decoded: Dict[str, np.ndarray] = {}
+    try:
+        for k, (dtype, shape, buf) in payload.items():
+            if k == HANDOFF_META_KEY:
+                continue
+            decoded[k] = np.frombuffer(buf, dtype=dtype).reshape(shape)
+    except (ValueError, TypeError) as e:   # short buffer / bad dtype
+        raise HandoffCorrupted(f"undecodable hand-off entry "
+                               f"{k!r}: {e}") from None
+    return decoded
+
+
+def import_state(cache: Dict[str, Any], decoded: Dict[str, np.ndarray]
+                 ) -> None:
+    """Upload decoded entries into ``cache``, in one
+    ``handoff.import.<kind>`` span per kind.  KV rows at positions past
+    the payload's are zero by invariant (zero-init caches, masked
+    recompute), so a sliced KV entry reassembles into a fresh zero buffer
+    with ONE host->device transfer instead of an in-place scatter."""
+    for kind in ("ssm", "kv"):
+        ks = [k for k in decoded if is_kv(k) == (kind == "kv")]
+        if not ks:
+            continue
+        with timing.span(f"handoff.import.{kind}"):
+            for k in ks:
+                arr = decoded[k]
+                if kind == "kv":
+                    full = np.zeros(cache[k].shape, arr.dtype)
+                    full[:, :, :arr.shape[2]] = arr
+                    arr = full
+                cache[k] = timing.upload(arr)
 
 
 def _fit_kv(a, cap: int):
@@ -185,7 +247,7 @@ def _fit_kv(a, cap: int):
 
 @guarded_by("_lock", "_aot_cache", "_full_cache", rank=RANK_STATEFUL_RUNNER)
 class StatefulStageRunner:
-    """Compiles decode/full-sequence functions over contiguous unit ranges.
+    """Compiles decode/full-sequence functions over contiguous layer ranges.
 
     Mirrors ``StageRunner``'s caching contract: warm builds share one
     AOT-executable cache per ``(mode, range, avals)``; ``fresh=True``
@@ -193,18 +255,21 @@ class StatefulStageRunner:
 
     ``decode_impl`` selects the decode hot path: ``"kernel"`` routes
     decode attention through the Pallas ``flash_decode`` kernel and SSM
-    steps through the ``mamba_scan``/``ssd_scan`` kernels; ``"reference"``
-    keeps the XLA reference ops; ``"auto"`` resolves ONCE at construction
-    to kernel on TPU and reference on CPU (where the Pallas kernels only
-    run in interpret mode — correct, so tests pin ``"kernel"`` for
-    parity, but orders slower than XLA).  ``rolled`` collapses each
-    full-sequence range (prefill, recompute) and each decode span of
-    mamba layers into a ``lax.scan`` over the stacked per-layer weights
-    instead of an unrolled Python loop, shrinking the HLO and the
-    per-range AOT compile wall; attention-layer decode ranges read each
-    layer through a static index either way.  ``rolled=False`` keeps
-    the unrolled trace for parity tests and the decode
-    microbenchmark's A/B."""
+    scans (decode steps, admission and recompute) through the
+    ``mamba_scan``/``ssd_scan`` kernels; ``"reference"`` keeps the XLA
+    reference ops; ``"auto"`` resolves ONCE at construction to kernel on
+    TPU and reference on CPU (where the Pallas kernels only run in
+    interpret mode — correct, so tests pin ``"kernel"`` for parity, but
+    orders slower than XLA).  ``rolled`` collapses each full-sequence
+    range (prefill) into a ``lax.scan`` over the stacked per-layer
+    weights per run of alike layers instead of an unrolled Python loop,
+    shrinking the HLO and the per-range AOT compile wall; decode ranges
+    read each layer through a static index either way.  ``rolled=False``
+    keeps the unrolled trace for parity tests.
+
+    The stream between layers is the hidden state ``x`` (B, S, D); for
+    the hybrid family it is the pair ``(x, x0)``, x0 the token embedding
+    that every shared-block application reads."""
 
     def __init__(self, cfg: ArchConfig, params, *, max_seq: int = 128,
                  attn_impl: str = "chunked", decode_impl: str = "auto",
@@ -226,7 +291,7 @@ class StatefulStageRunner:
                            else "reference")
         self.resolved_decode_impl = decode_impl
         self.rolled = bool(rolled)
-        self.units = unit_list(cfg)
+        self.units = list(range(cfg.num_layers))
         self._aot_cache: Dict[Tuple, Any] = {}
         self._full_cache: Dict[Tuple[int, int], Any] = {}
         self._lock = make_lock("stateful-runner", RANK_STATEFUL_RUNNER)
@@ -234,6 +299,26 @@ class StatefulStageRunner:
     @property
     def _ssm_impl(self) -> str:
         return "pallas" if self.resolved_decode_impl == "kernel" else "jnp"
+
+    # -- the stream -------------------------------------------------------
+    def stream(self, x):
+        """The stream entering layer 0 from the embedding output ``x``."""
+        return (x, x) if self.cfg.family == "hybrid" else x
+
+    @staticmethod
+    def hidden(x):
+        """The hidden state of a stream."""
+        return x[0] if isinstance(x, tuple) else x
+
+    def boundary_bytes(self, split: int, x) -> int:
+        """Bytes of the stream ``x`` that cross the link at ``split``: the
+        hidden state, and x0 beside it while an application lies on the
+        cloud side (layers ``[split, L)``)."""
+        parts = x if isinstance(x, tuple) else (x,)
+        n = parts[0].size * parts[0].dtype.itemsize
+        if len(parts) > 1 and any(i >= split for i in self.cfg.app_layers):
+            n += parts[1].size * parts[1].dtype.itemsize
+        return int(n)
 
     def _attend(self, q, kc, vc, pos):
         """One-token attention vs the heads-major cache, routed per
@@ -281,266 +366,199 @@ class StatefulStageRunner:
         frac = (split + 1) / (self.cfg.num_layers + 2)
         return int(total * frac)
 
-    # -- one decoder unit, one token ------------------------------------
-    def _decode_unit(self, params, unit, x, cache, new, pos):
+    # -- one layer, one token ---------------------------------------------
+    # Decode ranges never scan over layers: at the TPU's default precision
+    # XLA hoists a scanned body's f32->bf16 operand convert out of the
+    # loop and materialises a bf16 copy of the whole range's weights on
+    # every token (2.97 GB of temporaries for 18 qwen2.5-3b layers), while
+    # a static per-layer index lets each dot read its f32 weights straight
+    # from the stacked parameter.
+
+    def _decode_unit(self, params, i, x, cache, new, pos):
         cfg = self.cfg
-        kind, idx = unit
-        if kind == "app" or cfg.family in _ATTN_FAMILIES:
-            kk, vk = _unit_state_keys(cfg, unit)
-            p = params["shared"] if kind == "app" \
-                else jax.tree.map(lambda a: a[idx], params["layers"])
+        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        if cfg.family in _ATTN_FAMILIES:
+            kk, vk = state_keys(cfg, i)
             B = x.shape[0]
-            h = T._apply_norm(cfg, p["ln1"], x)
-            q, k, v = T._project_qkv(cfg, p["attn"], h)
+            h = T._apply_norm(cfg, lp["ln1"], x)
+            q, k, v = T._project_qkv(cfg, lp["attn"], h)
             cos, sin = self._decode_rope(pos)
             q = Lyr.apply_rope(q, cos, sin)
             k = Lyr.apply_rope(k, cos, sin)
-            kc = self._cache_write(
-                cache[kk], k.transpose(0, 2, 1, 3).astype(cache[kk].dtype),
-                pos)
-            vc = self._cache_write(
-                cache[vk], v.transpose(0, 2, 1, 3).astype(cache[vk].dtype),
-                pos)
-            new[kk], new[vk] = kc, vc
-            att = self._attend(q, kc, vc, pos)
-            x = x + att.reshape(B, 1, -1) @ p["attn"]["wo"]
-            h2 = T._apply_norm(cfg, p["ln2"], x)
-            if "moe" in p:
-                ff, _ = Lyr.moe_layer(p["moe"], h2, top_k=cfg.moe.top_k,
+            att = self._attend_write(q, k, v, cache, new, kk, vk, pos)
+            x = x + att.reshape(B, 1, -1) @ lp["attn"]["wo"]
+            h2 = T._apply_norm(cfg, lp["ln2"], x)
+            if "moe" in lp:
+                ff, _ = Lyr.moe_layer(lp["moe"], h2, top_k=cfg.moe.top_k,
                                       capacity_factor=cfg.moe.capacity_factor)
             else:
-                ff = Lyr.mlp(p["mlp"], h2, gated=cfg.gated_mlp)
+                ff = Lyr.mlp(lp["mlp"], h2, gated=cfg.gated_mlp)
             return x + ff
-        ck, sk = _unit_state_keys(cfg, unit)
-        lp = jax.tree.map(lambda a: a[idx], params["layers"])
-        h = T._apply_norm(cfg, lp["ln"], x)
-        block = SSM.mamba1_block if cfg.family == "ssm" else SSM.mamba2_block
-        y, nc = block(lp["mamba"], h,
-                      cache={"conv": cache[ck], "ssm": cache[sk]}, cfg=cfg,
-                      impl=self._ssm_impl)
-        new[ck], new[sk] = nc["conv"], nc["ssm"]
-        return x + y
+        keys = state_keys(cfg, i)
+        ssm_cache = {"conv": cache[keys[0]], "ssm": cache[keys[1]]}
+        if cfg.family == "ssm":
+            h = T._apply_norm(cfg, lp["ln"], x)
+            y, nc = SSM.mamba1_block(lp["mamba"], h, cache=ssm_cache,
+                                     cfg=cfg, impl=self._ssm_impl)
+            new[keys[0]], new[keys[1]] = nc["conv"], nc["ssm"]
+            return x + y
+        h, x0 = x
+        t = None
+        if len(keys) == 4:              # application g before layer i
+            def attend(q, k, v):
+                return self._attend_write(q, k, v, cache, new, keys[2],
+                                          keys[3], pos), None
+            t, _ = T.hybrid_block(cfg, params, cfg.app_layers.index(i), h,
+                                  x0, self._decode_rope(pos), attend)
+        h, nc = T.hybrid_layer(cfg, lp, h, t, ssm_cache, impl=self._ssm_impl)
+        new[keys[0]], new[keys[1]] = nc["conv"], nc["ssm"]
+        return (h, x0)
 
-    # -- one decoder unit, full sequence --------------------------------
-    def _full_unit(self, params, unit, x, caches, rope_cs):
-        cfg = self.cfg
-        kind, idx = unit
-        if kind == "app" or cfg.family in _ATTN_FAMILIES:
-            kk, vk = _unit_state_keys(cfg, unit)
-            p = params["shared"] if kind == "app" \
-                else jax.tree.map(lambda a: a[idx], params["layers"])
-            x, (k, v), _ = T.attn_block_full(cfg, p, x, rope_cs,
-                                             impl=self.attn_impl,
-                                             window=cfg.sliding_window)
-            caches[kk] = _fit_kv(k, self.max_seq)
-            caches[vk] = _fit_kv(v, self.max_seq)
-            return x
-        ck, sk = _unit_state_keys(cfg, unit)
-        lp = jax.tree.map(lambda a: a[idx], params["layers"])
-        h = T._apply_norm(cfg, lp["ln"], x)
-        block = SSM.mamba1_block if cfg.family == "ssm" else SSM.mamba2_block
-        y, nc = block(lp["mamba"], h, cfg=cfg)
-        caches[ck], caches[sk] = nc["conv"], nc["ssm"]
-        return x + y
+    def _attend_write(self, q, k, v, cache, new, kk, vk, pos):
+        """Write the token's K/V into the heads-major caches ``kk``/``vk``
+        and attend against them."""
+        kc = self._cache_write(
+            cache[kk], k.transpose(0, 2, 1, 3).astype(cache[kk].dtype), pos)
+        vc = self._cache_write(
+            cache[vk], v.transpose(0, 2, 1, 3).astype(cache[vk].dtype), pos)
+        new[kk], new[vk] = kc, vc
+        return self._attend(q, kc, vc, pos)
 
-    # -- range functions -------------------------------------------------
-    # Two trace shapes per range: "unrolled" replays the Python loop over
-    # units (one HLO copy per layer — O(layers) program size, and the
-    # per-range AOT compile wall that dominates cold builds), "rolled"
-    # scans ONE layer body over the stacked per-layer weights and caches
-    # (params["layers"] is already stacked on a leading L axis).  Hybrid
-    # ranges roll per homogeneous segment: runs of mamba layers scan,
-    # each shared-attn application stays a single unrolled unit.
-    # Attention-layer DECODE ranges never scan: at the TPU's default
-    # precision XLA hoists the scanned body's f32->bf16 operand convert
-    # out of the loop and materialises a bf16 copy of the whole range's
-    # weights on every token (2.97 GB of temporaries for 18 qwen2.5-3b
-    # layers), while a static per-layer index lets each dot read its
-    # f32 weights straight from the stacked parameter.  Both traces
-    # honour the same (x, new_state, bounds) contract, so the
-    # session/hand-off machinery never sees the difference.
-
-    def _segments(self, u0: int, u1: int) -> List[Tuple[str, int, int]]:
-        """Units [u0, u1) as homogeneous spans: ``("layer", lo, hi)`` for
-        runs of consecutive decoder layers, ``("app", g, g+1)`` for each
-        shared-attention application."""
-        segs: List[Tuple[str, int, int]] = []
-        for kind, idx in self.units[u0:u1]:
-            if kind == "layer" and segs and segs[-1][0] == "layer" \
-                    and segs[-1][2] == idx:
-                segs[-1] = ("layer", segs[-1][1], idx + 1)
-            else:
-                segs.append((kind, idx, idx + 1))
-        return segs
-
-    def _decode_ssm_span(self, params, x, cache, new, pos, lo, hi):
-        """Scan the one-token mamba-layer body over layers [lo, hi)."""
-        cfg = self.cfg
-        lp = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-        conv_all = jnp.stack([cache[f"conv{i}"] for i in range(lo, hi)])
-        ssm_all = jnp.stack([cache[f"ssm{i}"] for i in range(lo, hi)])
-        block = SSM.mamba1_block if cfg.family == "ssm" else SSM.mamba2_block
-        impl = self._ssm_impl
-
-        def body(x, xs):
-            p, c, s0 = xs
-            bound = x
-            h = T._apply_norm(cfg, p["ln"], x)
-            y, nc = block(p["mamba"], h, cache={"conv": c, "ssm": s0},
-                          cfg=cfg, impl=impl)
-            return x + y, (bound, nc["conv"], nc["ssm"])
-
-        x, (bounds, convs, ssms) = jax.lax.scan(body, x,
-                                                (lp, conv_all, ssm_all))
-        for j, i in enumerate(range(lo, hi)):
-            new[f"conv{i}"], new[f"ssm{i}"] = convs[j], ssms[j]
-        return x, bounds
-
-    def _make_decode_fn_rolled(self, u0: int, u1: int):
-        segs = self._segments(u0, u1)
-        cfg = self.cfg
-
-        def fn(params, x, cache, pos):
-            new: Dict[str, Any] = {}
-            parts = []
-            for kind, lo, hi in segs:
-                if kind == "app" or cfg.family in _ATTN_FAMILIES:
-                    for i in range(lo, hi):
-                        parts.append(x[None])
-                        x = self._decode_unit(params, (kind, i), x, cache,
-                                              new, pos)
-                else:
-                    x, b = self._decode_ssm_span(params, x, cache, new,
-                                                 pos, lo, hi)
-                    parts.append(b)
-            b = jnp.concatenate(parts, 0) if parts \
-                else jnp.zeros((0,) + x.shape, x.dtype)
-            return x, new, b
-        return fn
-
-    def _make_decode_fn_unrolled(self, u0: int, u1: int):
-        units = self.units[u0:u1]
-
+    def _make_decode_fn(self, u0: int, u1: int):
         def fn(params, x, cache, pos):
             new: Dict[str, Any] = {}
             bounds = []
-            for unit in units:
-                bounds.append(x)
-                x = self._decode_unit(params, unit, x, cache, new, pos)
+            for i in range(u0, u1):
+                bounds.append(self.hidden(x))
+                x = self._decode_unit(params, i, x, cache, new, pos)
+            h = self.hidden(x)
             b = jnp.stack(bounds) if bounds \
-                else jnp.zeros((0,) + x.shape, x.dtype)
+                else jnp.zeros((0,) + h.shape, h.dtype)
             return x, new, b
         return fn
 
-    def _make_decode_fn(self, u0: int, u1: int):
-        if self.rolled:
-            return self._make_decode_fn_rolled(u0, u1)
-        return self._make_decode_fn_unrolled(u0, u1)
+    # -- full-sequence ranges (prefill) -----------------------------------
+    # "unrolled" replays the Python loop over layers (one HLO copy per
+    # layer), "rolled" scans ONE layer body over the stacked per-layer
+    # weights per run of alike layers (hybrid layers that carry an
+    # application stay single unrolled steps).  Both honour the same
+    # (x, caches, bounds) contract.
 
-    def _full_attn_span(self, params, x, caches, rope_cs, lo, hi):
+    def _full_unit(self, params, i, x, caches, rope_cs):
         cfg = self.cfg
-        lp = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-
-        def body(x, p):
-            bound = x
-            x, (k, v), _ = T.attn_block_full(cfg, p, x, rope_cs,
+        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        keys = state_keys(cfg, i)
+        if cfg.family in _ATTN_FAMILIES:
+            x, (k, v), _ = T.attn_block_full(cfg, lp, x, rope_cs,
                                              impl=self.attn_impl,
                                              window=cfg.sliding_window)
-            return x, (bound, k, v)
+            caches[keys[0]] = _fit_kv(k, self.max_seq)
+            caches[keys[1]] = _fit_kv(v, self.max_seq)
+            return x
+        if cfg.family == "ssm":
+            h = T._apply_norm(cfg, lp["ln"], x)
+            y, nc = SSM.mamba1_block(lp["mamba"], h, cfg=cfg)
+            caches[keys[0]], caches[keys[1]] = nc["conv"], nc["ssm"]
+            return x + y
+        h, x0 = x
+        t = None
+        if len(keys) == 4:
+            t, (k, v) = T.hybrid_block(cfg, params, cfg.app_layers.index(i),
+                                       h, x0, rope_cs, self._full_attend)
+            caches[keys[2]] = _fit_kv(k, self.max_seq)
+            caches[keys[3]] = _fit_kv(v, self.max_seq)
+        h, nc = T.hybrid_layer(cfg, lp, h, t)
+        caches[keys[0]], caches[keys[1]] = nc["conv"], nc["ssm"]
+        return (h, x0)
 
-        x, (bounds, ks, vs) = jax.lax.scan(body, x, lp)
-        for j, i in enumerate(range(lo, hi)):
-            caches[f"k{i}"] = _fit_kv(ks[j], self.max_seq)
-            caches[f"v{i}"] = _fit_kv(vs[j], self.max_seq)
-        return x, bounds
+    def _full_attend(self, q, k, v):
+        return Lyr.attention(q, k, v, causal=True,
+                             window=self.cfg.sliding_window,
+                             impl=self.attn_impl), (k, v)
 
-    def _full_ssm_span(self, params, x, caches, lo, hi):
+    def _full_span(self, params, x, caches, rope_cs, lo, hi):
+        """Scan the full-sequence body of layers [lo, hi), all alike and
+        none carrying an application."""
         cfg = self.cfg
         lp = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-        block = SSM.mamba1_block if cfg.family == "ssm" else SSM.mamba2_block
+        x0 = x[1] if isinstance(x, tuple) else None
 
-        def body(x, p):
-            bound = x
-            h = T._apply_norm(cfg, p["ln"], x)
-            y, nc = block(p["mamba"], h, cfg=cfg)
-            return x + y, (bound, nc["conv"], nc["ssm"])
+        def body(h, p):
+            bound = h
+            if cfg.family in _ATTN_FAMILIES:
+                h, (k, v), _ = T.attn_block_full(cfg, p, h, rope_cs,
+                                                 impl=self.attn_impl,
+                                                 window=cfg.sliding_window)
+                return h, (bound, _fit_kv(k, self.max_seq),
+                           _fit_kv(v, self.max_seq))
+            if cfg.family == "ssm":
+                y, nc = SSM.mamba1_block(p["mamba"],
+                                         T._apply_norm(cfg, p["ln"], h),
+                                         cfg=cfg)
+                h = h + y
+            else:
+                h, nc = T.hybrid_layer(cfg, p, h, None)
+            return h, (bound, nc["conv"], nc["ssm"])
 
-        x, (bounds, convs, ssms) = jax.lax.scan(body, x, lp)
+        h, (bounds, a, b) = jax.lax.scan(body, self.hidden(x), lp)
         for j, i in enumerate(range(lo, hi)):
-            caches[f"conv{i}"], caches[f"ssm{i}"] = convs[j], ssms[j]
-        return x, bounds
+            k0, k1 = state_keys(cfg, i)[:2]
+            caches[k0], caches[k1] = a[j], b[j]
+        return (h if x0 is None else (h, x0)), bounds
 
-    def _make_full_fn_rolled(self, u0: int, u1: int):
-        segs = self._segments(u0, u1)
+    def _runs(self, u0: int, u1: int) -> List[Tuple[int, int]]:
+        """Layers [u0, u1) as runs that scan together: maximal runs of
+        plain layers, and each hybrid layer with an application alone."""
+        if self.cfg.family == "hybrid":
+            return [(i, i + 1) if kind == "app" else (i, j) for kind, i, j
+                    in T.hybrid_segments(self.cfg, u0, u1)]
+        return [(u0, u1)] if u1 > u0 else []
+
+    def _make_full_fn(self, u0: int, u1: int):
         cfg = self.cfg
+        apps = set(cfg.app_layers)
 
         def fn(params, x):
-            S = x.shape[1]
+            S = self.hidden(x).shape[1]
             rope_cs = Lyr.rope_cos_sin(jnp.arange(S), cfg.head_dim,
                                        cfg.rope_theta)
             caches: Dict[str, Any] = {}
             parts = []
-            for kind, lo, hi in segs:
-                if kind == "app":
-                    for g in range(lo, hi):
-                        parts.append(x[None])
-                        x = self._full_unit(params, ("app", g), x, caches,
-                                            rope_cs)
-                elif cfg.family in _ATTN_FAMILIES:
-                    x, b = self._full_attn_span(params, x, caches, rope_cs,
-                                                lo, hi)
+            for lo, hi in self._runs(u0, u1):
+                if self.rolled and lo not in apps:
+                    x, b = self._full_span(params, x, caches, rope_cs, lo, hi)
                     parts.append(b)
-                else:
-                    x, b = self._full_ssm_span(params, x, caches, lo, hi)
-                    parts.append(b)
+                    continue
+                for i in range(lo, hi):
+                    parts.append(self.hidden(x)[None])
+                    x = self._full_unit(params, i, x, caches, rope_cs)
+            h = self.hidden(x)
             b = jnp.concatenate(parts, 0) if parts \
-                else jnp.zeros((0,) + x.shape, x.dtype)
+                else jnp.zeros((0,) + h.shape, h.dtype)
             return x, caches, b
         return fn
-
-    def _make_full_fn_unrolled(self, u0: int, u1: int):
-        units = self.units[u0:u1]
-
-        def fn(params, x):
-            S = x.shape[1]
-            rope_cs = Lyr.rope_cos_sin(jnp.arange(S), self.cfg.head_dim,
-                                       self.cfg.rope_theta)
-            caches: Dict[str, Any] = {}
-            bounds = []
-            for unit in units:
-                bounds.append(x)
-                x = self._full_unit(params, unit, x, caches, rope_cs)
-            b = jnp.stack(bounds) if bounds \
-                else jnp.zeros((0,) + x.shape, x.dtype)
-            return x, caches, b
-        return fn
-
-    def _make_full_fn(self, u0: int, u1: int):
-        if self.rolled:
-            return self._make_full_fn_rolled(u0, u1)
-        return self._make_full_fn_unrolled(u0, u1)
 
     # -- masked re-prefill (the recompute hand-off arm) ------------------
     # The recompute arm runs at whatever context length the stream has
     # reached, so an exact-shape jit would recompile on every hand-off.
     # Instead the context is zero-padded to ``max_seq`` (ONE compile per
-    # unit range, ever) and correctness beyond the live length is
+    # layer range, ever) and correctness beyond the live length is
     # enforced the way bucketed prefills do it: causal attention already
     # ignores the pad for valid rows (pad rows are masked out of the
     # cache), and the recurrent state freezes at the live length because
     # a masked dt makes every padded step an identity update
     # (decay = exp(0 * A) = 1, update = 0).
 
-    def _masked_mamba(self, lp, x, mask, length):
+    def _masked_mamba(self, lp, x, mask, length, t=None):
         cfg = self.cfg
         s = cfg.ssm
-        di = cfg.d_inner
         B = x.shape[0]
         # mask: (CL,) shared across the batch, or (B, CL) per-row (slot
         # pools); either way dt sees its batched (B, CL, 1) form — the
         # shared path broadcasts exactly as it always did
-        mask_b = mask[None] if mask.ndim == 1 else mask
-        h = T._apply_norm(cfg, lp["ln"], x)
+        mask_b = jnp.broadcast_to(mask[None] if mask.ndim == 1 else mask,
+                                  x.shape[:2])
+        h = T._apply_norm(cfg, lp["ln"], x if t is None else x + t)
         p = lp["mamba"]
         if cfg.family == "ssm":            # mamba1
             xz = h @ p["in_proj"]
@@ -560,27 +578,9 @@ class StatefulStageRunner:
             out = y @ p["out_proj"]
             conv_src = xin
         else:                              # mamba2 (hybrid backbone)
-            H = di // s.head_dim
-            N = s.d_state
-            zxbcdt = h @ p["in_proj"]
-            z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * N], axis=-1)
-            xbc_c, _ = SSM.causal_conv1d(xbc, p["conv_w"], p["conv_b"])
-            xbc_c = jax.nn.silu(xbc_c)
-            xin, Bc, Cc = jnp.split(xbc_c, [di, di + N], axis=-1)
-            S_len = x.shape[1]
-            xh = xin.reshape(B, S_len, H, s.head_dim)
-            dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"]) \
-                * mask_b[:, :, None]
-            A = -jnp.exp(p["A_log"])
-            y, hs = SSM.mamba2_scan(dt, Bc, Cc, xh, A)
-            y = y + xh.astype(jnp.float32) * p["D"][:, None]
-            y = y.reshape(B, S_len, di).astype(x.dtype)
-            y = y * jax.nn.silu(z)
-            var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1,
-                           keepdims=True)
-            y = (y * jax.lax.rsqrt(var + 1e-5).astype(y.dtype)) * p["norm"]
-            out = y @ p["out_proj"]
-            conv_src = xbc
+            out, conv_src, hs, _ = SSM.mamba2_mix(
+                p, h @ p["in_proj"], dt_mask=mask_b, cfg=cfg,
+                impl=self._ssm_impl)
         # conv state = the K-1 raw inputs trailing the LIVE length, not
         # the pad (dynamic_slice at the traced length; per-row lengths
         # slice each row at its own live prefix)
@@ -597,45 +597,67 @@ class StatefulStageRunner:
             )(cat, length)
         return x + out, {"conv": conv_state, "ssm": hs}
 
-    def _make_recompute_fn(self, u0: int, u1: int):
-        units = self.units[u0:u1]
+    def _masked_range(self, params, x, x0, u0, u1, mask2, length,
+                      bounds=None):
+        """Layers [u0, u1) over a zero-padded (B, CL) context whose live
+        prefix is ``mask2``: the state of every layer, KV masked so slot
+        buffers stay zero beyond each row's prefix.  ``bounds`` collects
+        each layer's (masked) input.  Returns (x, caches)."""
         cfg = self.cfg
-        CL = self.max_seq
+        CL = x.shape[1]
+        m3, m4 = mask2[:, :, None], mask2[:, :, None, None]
+        rope_cs = Lyr.rope_cos_sin(jnp.arange(CL), cfg.head_dim,
+                                   cfg.rope_theta)
+        caches: Dict[str, Any] = {}
+        for i in range(u0, u1):
+            if bounds is not None:
+                bounds.append(x * m3)
+            keys = state_keys(cfg, i)
+            lp = jax.tree.map(lambda a: a[i], params["layers"])
+            if cfg.family in _ATTN_FAMILIES:
+                x, (k, v), _ = T.attn_block_full(
+                    cfg, lp, x, rope_cs, impl=self.attn_impl,
+                    window=cfg.sliding_window)
+                caches[keys[0]] = (k * m4).transpose(0, 2, 1, 3)
+                caches[keys[1]] = (v * m4).transpose(0, 2, 1, 3)
+                continue
+            t = None
+            if len(keys) == 4:
+                t, (k, v) = T.hybrid_block(cfg, params,
+                                           cfg.app_layers.index(i), x, x0,
+                                           rope_cs, self._full_attend)
+                caches[keys[2]] = (k * m4).transpose(0, 2, 1, 3)
+                caches[keys[3]] = (v * m4).transpose(0, 2, 1, 3)
+            x, st = self._masked_mamba(lp, x, mask2, length, t)
+            caches[keys[0]], caches[keys[1]] = st["conv"], st["ssm"]
+        return x, caches
 
-        def fn(params, x, length):
-            # x: (B, CL, D) zero-padded context; length: live prefix — a
-            # scalar shared by the batch or per-row (B,) (slot pools)
-            if jnp.ndim(length) == 0:
-                mask = (jnp.arange(CL) < length)
-                m = mask[None, :, None, None]
-            else:
-                mask = (jnp.arange(CL)[None, :] < length[:, None])
-                m = mask[:, :, None, None]
-            rope_cs = Lyr.rope_cos_sin(jnp.arange(CL), cfg.head_dim,
-                                       cfg.rope_theta)
-            caches: Dict[str, Any] = {}
-            for unit in units:
-                kind, idx = unit
-                if kind == "app" or cfg.family in _ATTN_FAMILIES:
-                    kk, vk = _unit_state_keys(cfg, unit)
-                    p = params["shared"] if kind == "app" \
-                        else jax.tree.map(lambda a: a[idx], params["layers"])
-                    x, (k, v), _ = T.attn_block_full(
-                        cfg, p, x, rope_cs, impl=self.attn_impl,
-                        window=cfg.sliding_window)
-                    caches[kk] = (k * m).transpose(0, 2, 1, 3)
-                    caches[vk] = (v * m).transpose(0, 2, 1, 3)
-                else:
-                    ck, sk = _unit_state_keys(cfg, unit)
-                    lp = jax.tree.map(lambda a: a[idx], params["layers"])
-                    x, st = self._masked_mamba(lp, x, mask, length)
-                    caches[ck], caches[sk] = st["conv"], st["ssm"]
-            return caches
+    @staticmethod
+    def _live_mask(length, CL):
+        """(1, CL) for a scalar live length shared by the batch, (B, CL)
+        per row."""
+        if jnp.ndim(length) == 0:
+            return (jnp.arange(CL) < length)[None]
+        return jnp.arange(CL)[None, :] < length[:, None]
+
+    def _make_recompute_fn(self, u0: int, u1: int):
+        def fn(params, x, tokens, length):
+            # x: (B, CL, D) zero-padded boundary checkpoint entering layer
+            # u0; tokens: (B, CL) the context, whose embedding is x0 for
+            # the hybrid's applications; length: live prefix — a scalar
+            # shared by the batch or per-row (B,) (slot pools)
+            mask2 = jnp.broadcast_to(self._live_mask(length, x.shape[1]),
+                                     x.shape[:2])
+            x0 = params["embed"][tokens] if self.cfg.family == "hybrid" \
+                else None
+            return self._masked_range(params, x, x0, u0, u1, mask2,
+                                      length)[1]
         return fn
 
     def recompute_fn(self, u0: int, u1: int):
-        """Cached masked re-prefill fn for units [u0, u1) — compiled once
-        per range, reused at every context length."""
+        """Cached masked re-prefill fn ``(params, x, tokens, length) ->
+        caches`` for layers [u0, u1) — compiled once per range, reused at
+        every context length."""
         with self._lock:
             key = ("recompute", u0, u1)
             if key not in self._full_cache:
@@ -646,50 +668,26 @@ class StatefulStageRunner:
     # -- masked admission (slot pools) -----------------------------------
     # Admitting a session into a live slot pool is a masked prefill at the
     # pool's fixed (B, max_seq) bucket: the same zero-pad + masked-dt
-    # trick as the recompute arm, extended to also return the per-unit
+    # trick as the recompute arm, extended to also return the per-layer
     # boundary activations and the logits at each row's last live token.
     # Compiled once per bucket shape, reused for every mid-flight join.
 
     def _make_admit_fn(self):
         cfg = self.cfg
         CL = self.max_seq
-        units = self.units
 
         def fn(params, tokens, length):
             # tokens: (B, CL) zero-padded; length: live prefix — scalar
             # shared by the batch or per-row (B,)
             B = tokens.shape[0]
-            if jnp.ndim(length) == 0:
-                mask2 = (jnp.arange(CL) < length)[None]
-            else:
-                mask2 = (jnp.arange(CL)[None, :] < length[:, None])
-            m3 = mask2[:, :, None]
-            m4 = mask2[:, :, None, None]
-            rope_cs = Lyr.rope_cos_sin(jnp.arange(CL), cfg.head_dim,
-                                       cfg.rope_theta)
+            mask2 = jnp.broadcast_to(self._live_mask(length, CL), (B, CL))
             x = params["embed"][tokens]
-            caches: Dict[str, Any] = {}
+            # boundary checkpoints are stored masked so slot buffers keep
+            # the zero-beyond-live-prefix invariant the sliced KV
+            # export/import path relies on
             bounds = []
-            for unit in units:
-                # boundary checkpoints are stored masked so slot buffers
-                # keep the zero-beyond-live-prefix invariant the sliced
-                # KV export/import path relies on
-                bounds.append(x * m3)
-                kind, idx = unit
-                if kind == "app" or cfg.family in _ATTN_FAMILIES:
-                    kk, vk = _unit_state_keys(cfg, unit)
-                    p = params["shared"] if kind == "app" \
-                        else jax.tree.map(lambda a: a[idx], params["layers"])
-                    x, (k, v), _ = T.attn_block_full(
-                        cfg, p, x, rope_cs, impl=self.attn_impl,
-                        window=cfg.sliding_window)
-                    caches[kk] = (k * m4).transpose(0, 2, 1, 3)
-                    caches[vk] = (v * m4).transpose(0, 2, 1, 3)
-                else:
-                    ck, sk = _unit_state_keys(cfg, unit)
-                    lp = jax.tree.map(lambda a: a[idx], params["layers"])
-                    x, st = self._masked_mamba(lp, x, mask2, length)
-                    caches[ck], caches[sk] = st["conv"], st["ssm"]
+            x, caches = self._masked_range(params, x, x, 0, cfg.num_layers,
+                                           mask2, length, bounds)
             D = x.shape[-1]
             if jnp.ndim(length) == 0:
                 last = jax.lax.dynamic_slice(x, (0, length - 1, 0),
@@ -711,7 +709,7 @@ class StatefulStageRunner:
 
     def admit_fn(self):
         """Cached masked-admission fn ``(params, tokens, length) ->
-        (last_logits, caches, bounds)`` over the full unit range."""
+        (last_logits, caches, bounds)`` over the full layer range."""
         with self._lock:
             if ("admit",) not in self._full_cache:
                 self._full_cache[("admit",)] = jax.jit(self._make_admit_fn())
@@ -719,14 +717,14 @@ class StatefulStageRunner:
 
     def _make_embed_fn(self):
         def fn(params, tokens):
-            return params["embed"][tokens]
+            return self.stream(params["embed"][tokens])
         return fn
 
     def _make_head_fn(self):
         cfg = self.cfg
 
         def fn(params, x):
-            x = T._apply_norm(cfg, params["final_norm"], x)
+            x = T._apply_norm(cfg, params["final_norm"], self.hidden(x))
             return (x[:, -1] @ T.lm_head_weights(cfg, params)).astype(
                 jnp.float32)
         return fn
@@ -734,10 +732,11 @@ class StatefulStageRunner:
     # -- compiled executables -------------------------------------------
     def executable(self, mode: str, u0: int, u1: int, params, *args,
                    fresh: bool = False, shardings=None, mesh=None):
-        """AOT executable for a unit range, specialized to the arg avals.
+        """AOT executable for a layer range, specialized to the arg avals.
 
         ``mode``: ``decode`` (params, x, cache, pos), ``full`` (params, x),
-        ``embed`` (params, tokens), ``head`` (params, x).
+        ``embed`` (params, tokens), ``head`` (params, x); ``x`` is the
+        stream (``stream``).
 
         ``mesh`` + ``shardings`` compile a tensor-parallel executable:
         ``shardings`` is the jit ``in_shardings`` tuple over
@@ -773,8 +772,8 @@ class StatefulStageRunner:
         return compiled
 
     def full_fn(self, u0: int, u1: int):
-        """Warm (retracing-jit) full-sequence fn — the prefill/recompute
-        path, shape-polymorphic over the growing context."""
+        """Warm (retracing-jit) full-sequence fn — the prefill path,
+        shape-polymorphic over the growing context."""
         with self._lock:
             if (u0, u1) not in self._full_cache:
                 self._full_cache[(u0, u1)] = jax.jit(
@@ -825,9 +824,10 @@ class DecodeSession:
         U = len(r.units)
         if tokens.shape[1] > r.max_seq:
             raise ValueError(f"prompt {tokens.shape[1]} > max_seq {r.max_seq}")
-        x = r.params["embed"][tokens]
+        x = r.stream(r.params["embed"][tokens])
         x, caches, bounds = r.full_fn(0, U)(r.params, x)
-        logits = (T._apply_norm(self.cfg, r.params["final_norm"], x)[:, -1]
+        logits = (T._apply_norm(self.cfg, r.params["final_norm"],
+                                r.hidden(x))[:, -1]
                   @ T.lm_head_weights(self.cfg, r.params)).astype(jnp.float32)
         jax.block_until_ready(logits)
         # calibration wall from a second, warm run: the first call paid
@@ -935,11 +935,8 @@ class DecodeSession:
     def subset(self, u0: int, u1: int) -> Dict[str, Any]:
         """The state entries a stage over units [u0, u1) reads/writes."""
         with self._lock:
-            out = {}
-            for unit in self.runner.units[u0:u1]:
-                for k in _unit_state_keys(self.cfg, unit):
-                    out[k] = self.cache[k]
-            return out
+            return {k: self.cache[k] for i in range(u0, u1)
+                    for k in state_keys(self.cfg, i)}
 
     # -- hand-off primitives ---------------------------------------------
     def export_layers(self, lo: int, hi: int
@@ -951,19 +948,10 @@ class DecodeSession:
         ``(epoch, pos, crc32)`` — that ``import_layers`` validates before
         committing anything, so in-transit corruption is detected rather
         than served."""
-        u0 = unit_index_of_split(self.cfg, lo)
-        u1 = unit_index_of_split(self.cfg, hi)
-        payload: Dict[str, tuple] = {}
-        nbytes = 0
         with self._lock:
-            for unit in self.runner.units[u0:u1]:
-                for k in _unit_state_keys(self.cfg, unit):
-                    arr = timing.fetch(self.cache[k])
-                    if k[0] in ("k", "v", "a"):      # KV: valid region only
-                        arr = arr[:, :, :self.pos]
-                    buf = arr.tobytes()
-                    payload[k] = (str(arr.dtype), arr.shape, buf)
-                    nbytes += len(buf)
+            payload, nbytes = export_state(
+                self.cache, [k for i in range(lo, hi)
+                             for k in state_keys(self.cfg, i)], self.pos)
             payload[HANDOFF_META_KEY] = (self.epoch, self.pos,
                                          payload_checksum(payload))
         return payload, nbytes
@@ -999,23 +987,9 @@ class DecodeSession:
         ``HandoffCorrupted`` with the session state untouched, so a
         caller's recompute fallback starts from pristine state."""
         self.validate_payload(payload)
-        decoded: Dict[str, np.ndarray] = {}
-        try:
-            for k, (dtype, shape, buf) in payload.items():
-                if k == HANDOFF_META_KEY:
-                    continue
-                decoded[k] = np.frombuffer(buf, dtype=dtype).reshape(shape)
-        except (ValueError, TypeError) as e:   # short buffer / bad dtype
-            raise HandoffCorrupted(f"undecodable hand-off entry "
-                                   f"{k!r}: {e}") from None
+        decoded = decode_payload(payload)
         with self._lock:
-            for k, arr in decoded.items():
-                if k[0] in ("k", "v", "a"):
-                    full = np.zeros(self.cache[k].shape, arr.dtype)
-                    full[:, :, :arr.shape[2]] = arr
-                    self.cache[k] = timing.upload(full)
-                else:
-                    self.cache[k] = timing.upload(arr)
+            import_state(self.cache, decoded)
 
     def recompute_layers(self, lo: int, hi: int) -> None:
         """Re-prefill layers [lo, hi) over the full live context from the
@@ -1023,17 +997,19 @@ class DecodeSession:
 
         Runs the masked fixed-shape path: padded to ``max_seq`` so the
         compiled executable is reused at every context length."""
-        u0 = unit_index_of_split(self.cfg, lo)
-        u1 = unit_index_of_split(self.cfg, hi)
-        if u0 >= u1:
+        if lo >= hi:
             return
         r = self.runner
         with self._lock:
-            x0 = self.bounds[u0]                       # (B, T, D)
+            x0 = self.bounds[lo]                       # (B, T, D)
+            tokens = self.tokens
         B, T_len, D = x0.shape
         x_pad = np.zeros((B, r.max_seq, D), x0.dtype)
         x_pad[:, :T_len] = x0
-        caches = r.recompute_fn(u0, u1)(r.params, timing.upload(x_pad),
+        tok_pad = np.zeros((B, r.max_seq), np.int32)
+        tok_pad[:, :T_len] = tokens
+        caches = r.recompute_fn(lo, hi)(r.params, timing.upload(x_pad),
+                                        timing.upload(tok_pad),
                                         jnp.int32(T_len))
         timing.block(caches)
         with self._lock:
@@ -1095,8 +1071,8 @@ class StatefulEdgeCloudPipeline:
         self._cloud_state_shardings = None  # cloud-range decode state
         self._repl = None                   # replicated sharding on the mesh
         self._edge_sharding = None          # where edge-stage operands live
-        self._u_edge = unit_index_of_split(runner.cfg, self.split)
-        self._u_all = len(runner.units)
+        self._u_edge = self.split
+        self._u_all = runner.num_units
         self.embed_fn = None
         self.edge_fn = None
         self.cloud_fn = None
@@ -1125,7 +1101,7 @@ class StatefulEdgeCloudPipeline:
 
         s = self.session
         B, D = s.batch, r.cfg.d_model
-        x_av = jax.ShapeDtypeStruct((B, 1, D), jnp.float32)
+        x_av = r.stream(jax.ShapeDtypeStruct((B, 1, D), jnp.float32))
         tok_av = jax.ShapeDtypeStruct((B, 1), jnp.int32)
         # scalar for the single-stream session, (num_slots,) for slot
         # pools — the compiled stages follow the session's position shape
@@ -1243,8 +1219,9 @@ class StatefulEdgeCloudPipeline:
             xe, new_e, b_e = self.edge_fn(self.params, x, cache_edge, pos)
             timing.block(xe)
         t_edge = m.wall * self.edge_scale
-        t_transfer = self.net.transfer_time(
-            int(np.prod(xe.shape)) * xe.dtype.itemsize)
+        nbytes = self.runner.boundary_bytes(self._u_edge, xe)
+        timing.count("boundary_bytes", nbytes)
+        t_transfer = self.net.transfer_time(nbytes)
         with timing.span("step.cloud", timed=True) as m:
             new_c, b_c, logits = self._run_cloud(xe, cache_cloud, pos)
         t_cloud = m.wall
@@ -1430,6 +1407,7 @@ class StatefulPipelinePool(PipelinePool):
             with timing.span("handoff.recompute", timed=True, mode=mode,
                              layers=hi - lo) as m:
                 s.recompute_layers(lo, hi)
+                count_state(s.subset(lo, hi), s.pos)
             t_wall += m.wall
         return HandoffReport(mode, hi - lo, nbytes, t_wall, t_network,
                              plan, s.epoch, fallback=fallback)

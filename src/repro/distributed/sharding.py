@@ -89,6 +89,7 @@ def _param_spec(name: str, ndim: int, *, fsdp, tp, shard_fsdp: bool,
         "wq": P(f, tp), "wk": P(f, tp), "wv": P(f, tp), "wo": P(tp, f),
         "bq": P(tp), "bk": P(tp), "bv": P(tp),
         "w_gate": P(f, tp), "w_up": P(f, tp), "w_down": P(tp, f),
+        "w_gate_up": P(f, tp),
         "shared_w_gate": P(f, tp), "shared_w_up": P(f, tp),
         "shared_w_down": P(tp, f),
         "router": P(f, None),

@@ -102,7 +102,13 @@ def ssd_scan(dt, Bc, Cc, x, A, h0=None):
     if _MESH.get() is None:
         return _ssd_scan(dt, Bc, Cc, x, A, h0)
     ax = _model_axis(dt.shape[-1])                 # SSM heads
+    if ax is not None:
+        # a shard's heads need not start a group: give each head its own
+        # group's B/C, so that the per-shard kernel maps head to group 1:1
+        H, G = dt.shape[-1], Bc.shape[-2]
+        Bc = jnp.repeat(Bc, H // G, axis=2)
+        Cc = jnp.repeat(Cc, H // G, axis=2)
     y, state = P(None, None, ax, None), P(None, ax, None, None)
     return _per_shard(_ssd_scan, (dt, Bc, Cc, x, A, h0),
-                      (P(None, None, ax), P(), P(), y, P(ax), state),
+                      (P(None, None, ax), y, y, y, P(ax), state),
                       (y, state))

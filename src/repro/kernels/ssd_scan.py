@@ -11,7 +11,10 @@ systolic array wants:
 
 All decay ratios are <= 1 (A < 0), so the form is numerically stable.  The
 recurrent state h (P, N) stays in VMEM scratch across the sequential chunk
-grid dimension.  Validated against models.ssm.mamba2_scan in interpret mode.
+grid dimension.  B and C come in groups (Mamba-2's ``n_groups``): head h
+reads group h // (H / G), which the block index map picks, so no per-head
+copy of B or C is made.  Validated against models.ssm.mamba2_scan in
+interpret mode.
 """
 from __future__ import annotations
 
@@ -40,8 +43,8 @@ def _ssd_kernel(rows_ref, cols_ref, b_ref, c_ref, x_ref, h0_ref, y_ref,
     cols = cols_ref[0, 0]                                   # (chunk, 3)
     dt_r, cum_r = rows[0:1, :], rows[1:2, :]                # (1, chunk)
     dt_c, cum_c, tail_c = cols[:, 0:1], cols[:, 1:2], cols[:, 2:3]
-    Bc = b_ref[0].astype(jnp.float32)                       # (chunk, N)
-    Cc = c_ref[0].astype(jnp.float32)                       # (chunk, N)
+    Bc = b_ref[0, 0].astype(jnp.float32)                    # (chunk, N)
+    Cc = c_ref[0, 0].astype(jnp.float32)                    # (chunk, N)
     xh = x_ref[0, 0].astype(jnp.float32)                    # (chunk, P)
 
     alpha = jnp.exp(cum_c)                                  # (chunk, 1)
@@ -76,12 +79,13 @@ def _ssd_kernel(rows_ref, cols_ref, b_ref, c_ref, x_ref, h0_ref, y_ref,
 
 
 def ssd_scan(dt, Bc, Cc, x, A, h0=None, *, chunk=128, interpret=None):
-    """Mamba-2 SSD.  dt: (B,S,H)  Bc/Cc: (B,S,N)  x: (B,S,H,P)  A: (H,).
+    """Mamba-2 SSD.  dt: (B,S,H)  Bc/Cc: (B,S,G,N)  x: (B,S,H,P)  A: (H,).
 
     Returns (y (B,S,H,P) fp32-accurate, h_final (B,H,P,N) fp32).
     """
     B, S, H = dt.shape
-    P, N = x.shape[-1], Bc.shape[-1]
+    P, G, N = x.shape[-1], Bc.shape[-2], Bc.shape[-1]
+    hpg = H // G                          # heads per group
     if interpret is None:
         # nk: allow[NK03]: per-backend constant is deliberate (interpret on CPU)
         interpret = jax.default_backend() == "cpu"
@@ -105,8 +109,10 @@ def ssd_scan(dt, Bc, Cc, x, A, h0=None, *, chunk=128, interpret=None):
     rows = jnp.stack([dtp, cum], axis=1).transpose(0, 3, 1, 2)   # (B,H,2,Sp)
     cols = jnp.stack([dtp, cum, tail], axis=-1).transpose(0, 2, 1, 3)
     xp = padseq(x).transpose(0, 2, 1, 3)                    # (B, H, Sp, P)
-    Bp = padseq(Bc)
-    Cp = padseq(Cc)
+    # (B, G, Sp, N): a block's last two dims are (chunk, N), which the
+    # TPU's tiling takes; a (1, N) group slice of (B, S, G, N) it does not
+    Bp = padseq(Bc).transpose(0, 2, 1, 3)
+    Cp = padseq(Cc).transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk, num_chunks=nc)
     y, hout = pl.pallas_call(
@@ -115,8 +121,8 @@ def ssd_scan(dt, Bc, Cc, x, A, h0=None, *, chunk=128, interpret=None):
         in_specs=[
             pl.BlockSpec((1, 1, 2, chunk), lambda b, h, c: (b, h, 0, c)),
             pl.BlockSpec((1, 1, chunk, 3), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h // hpg, c, 0)),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h // hpg, c, 0)),
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],

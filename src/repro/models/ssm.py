@@ -87,25 +87,25 @@ def mamba1_scan(dt, Bc, Cc, x, A, h0=None, chunk=256, impl="jnp"):
 def mamba2_scan(dt, Bc, Cc, x, A, h0=None, chunk=64, impl="jnp"):
     # chunk=64 (vs 256 for mamba1): the mamba2 state (H, P, N) is ~16x
     # larger per step, and backward saves per-step h within a chunk.
-    """SSD with scalar-per-head decay.
+    """SSD with scalar-per-head decay and grouped B/C.
 
-    dt: (B,S,H)  Bc,Cc: (B,S,N)  x: (B,S,H,P)  A: (H,)  h: (B,H,P,N)
-    y_t = h_t . C_t  -> (B,S,H,P)
+    dt: (B,S,H)  Bc,Cc: (B,S,G,N)  x: (B,S,H,P)  A: (H,)  h: (B,H,P,N);
+    head h reads group h // (H // G).  y_t = h_t . C_t  -> (B,S,H,P)
     """
     if impl == "pallas":
         from repro.kernels import ops as kops
         y, h = kops.ssd_scan(dt, Bc, Cc, x, A, h0=h0)
         return y.astype(jnp.float32), h
     B, S, H = dt.shape
-    P, N = x.shape[-1], Bc.shape[-1]
+    P, G, N = x.shape[-1], Bc.shape[-2], Bc.shape[-1]
     chunk = min(chunk, S)
     nc = -(-S // chunk)
     pad = nc * chunk - S
     def padseq(a):
         return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
     dtp = padseq(dt).reshape(B, nc, chunk, H).transpose(1, 0, 2, 3)
-    Bp = padseq(Bc).reshape(B, nc, chunk, N).transpose(1, 0, 2, 3)
-    Cp = padseq(Cc).reshape(B, nc, chunk, N).transpose(1, 0, 2, 3)
+    Bp = padseq(Bc).reshape(B, nc, chunk, G, N).transpose(1, 0, 2, 3, 4)
+    Cp = padseq(Cc).reshape(B, nc, chunk, G, N).transpose(1, 0, 2, 3, 4)
     xp = padseq(x).reshape(B, nc, chunk, H, P).transpose(1, 0, 2, 3, 4)
     h = h0 if h0 is not None else jnp.zeros((B, H, P, N), jnp.float32)
 
@@ -113,18 +113,20 @@ def mamba2_scan(dt, Bc, Cc, x, A, h0=None, chunk=64, impl="jnp"):
         dtc, bc, cc, xc = blk
 
         def t_step(h, t):
-            dt_t, b_t, c_t, x_t = t   # (B,H) (B,N) (B,N) (B,H,P)
+            dt_t, b_t, c_t, x_t = t   # (B,H) (B,G,N) (B,G,N) (B,H,P)
+            b_t = jnp.repeat(b_t.astype(jnp.float32), H // G, axis=1)
+            c_t = jnp.repeat(c_t.astype(jnp.float32), H // G, axis=1)
             decay = jnp.exp(dt_t.astype(jnp.float32) * A)[:, :, None, None]
             upd = (dt_t[:, :, None].astype(jnp.float32) * x_t.astype(jnp.float32))[..., None] \
-                * b_t.astype(jnp.float32)[:, None, None, :]
+                * b_t[:, :, None, :]
             h = decay * h + upd
-            y = jnp.einsum("bhpn,bn->bhp", h, c_t.astype(jnp.float32))
+            y = jnp.einsum("bhpn,bhn->bhp", h, c_t)
             return h, y
 
         h, ys = jax.lax.scan(
             t_step, h,
-            (dtc.transpose(1, 0, 2), bc.transpose(1, 0, 2),
-             cc.transpose(1, 0, 2), xc.transpose(1, 0, 2, 3)))
+            (dtc.transpose(1, 0, 2), bc.transpose(1, 0, 2, 3),
+             cc.transpose(1, 0, 2, 3), xc.transpose(1, 0, 2, 3)))
         return h, ys.transpose(1, 0, 2, 3)
 
     # remat chunk body (see mamba1_scan): the mamba2 per-step state
@@ -191,10 +193,10 @@ def init_mamba2(cfg, key, dtype):
     s = cfg.ssm
     H = di // s.head_dim
     ks = jax.random.split(key, 4)
-    conv_dim = di + 2 * s.d_state
+    conv_dim = di + 2 * s.n_groups * s.d_state
     return {
         "in_proj": jax.random.normal(
-            ks[0], (d, 2 * di + 2 * s.d_state + H), dtype) * 0.02,
+            ks[0], (d, di + conv_dim + H), dtype) * 0.02,
         "conv_w": jax.random.normal(ks[1], (s.d_conv, conv_dim), dtype) * 0.2,
         "conv_b": jnp.zeros((conv_dim,), dtype),
         "dt_bias": jnp.zeros((H,), jnp.float32),
@@ -205,30 +207,45 @@ def init_mamba2(cfg, key, dtype):
     }
 
 
-def mamba2_block(params, x, cache=None, *, cfg, impl="jnp"):
-    """Mamba-2 (SSD, n_groups=1).  cache: {'conv': (B,K-1,Di+2N), 'ssm': (B,H,P,N)}."""
+def mamba2_mix(params, zxbcdt, dt_mask=None, h0=None, conv_state=None, *,
+               cfg, impl="jnp"):
+    """The Mamba-2 mixer after ``in_proj``: causal conv over x|B|C, the
+    grouped SSD scan, the D skip, the group-wise gated RMSNorm and
+    ``out_proj``.  ``dt_mask`` (B, S) zeroes dt on padded steps (an
+    identity update).  Returns (out, conv input xBC, h_final, conv
+    state after the sequence)."""
     s = cfg.ssm
     di = cfg.d_inner
-    H = di // s.head_dim
-    P, N = s.head_dim, s.d_state
-    zxbcdt = x @ params["in_proj"]
-    z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * N], axis=-1)
-    conv_state = cache["conv"] if cache is not None else None
-    xbc, new_conv = causal_conv1d(xbc, params["conv_w"], params["conv_b"],
+    H, P, G, N = di // s.head_dim, s.head_dim, s.n_groups, s.d_state
+    z, xbc_in, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * G * N], axis=-1)
+    xbc, new_conv = causal_conv1d(xbc_in, params["conv_w"], params["conv_b"],
                                   conv_state)
     xbc = jax.nn.silu(xbc)
-    xin, Bc, Cc = jnp.split(xbc, [di, di + N], axis=-1)
-    B_, S, _ = x.shape
+    xin, Bc, Cc = jnp.split(xbc, [di, di + G * N], axis=-1)
+    B_, S, _ = zxbcdt.shape
     xh = xin.reshape(B_, S, H, P)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+    if dt_mask is not None:
+        dt = dt * dt_mask[:, :, None]
     A = -jnp.exp(params["A_log"])
-    h0 = cache["ssm"] if cache is not None else None
-    y, h = mamba2_scan(dt, Bc, Cc, xh, A, h0=h0, impl=impl)
+    y, h = mamba2_scan(dt, Bc.reshape(B_, S, G, N), Cc.reshape(B_, S, G, N),
+                       xh, A, h0=h0, impl=impl)
     y = y + xh.astype(jnp.float32) * params["D"][:, None]
-    y = y.reshape(B_, S, di).astype(x.dtype)
-    # gated RMSNorm (mamba2)
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y * jax.lax.rsqrt(var + 1e-5).astype(y.dtype)) * params["norm"]
-    out = y @ params["out_proj"]
+    # gated RMSNorm over each group's di / G channels
+    y = y.reshape(B_, S, G, di // G) \
+        * jax.nn.silu(z.astype(jnp.float32)).reshape(B_, S, G, di // G)
+    var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+    y = (y * jax.lax.rsqrt(var + 1e-5)).reshape(B_, S, di).astype(
+        zxbcdt.dtype) * params["norm"]
+    return y @ params["out_proj"], xbc_in, h, new_conv
+
+
+def mamba2_block(params, x, cache=None, *, cfg, impl="jnp"):
+    """Mamba-2 (SSD, grouped B/C).  cache: {'conv': (B,K-1,Di+2GN),
+    'ssm': (B,H,P,N)}."""
+    out, _, h, new_conv = mamba2_mix(
+        params, x @ params["in_proj"],
+        h0=cache["ssm"] if cache is not None else None,
+        conv_state=cache["conv"] if cache is not None else None,
+        cfg=cfg, impl=impl)
     return out, {"conv": new_conv, "ssm": h}

@@ -3,9 +3,13 @@
 Families:
   dense / moe        stacked attn(+moe) layers, scan-over-layers
   ssm                stacked mamba1 layers
-  hybrid (zamba2)    mamba2 backbone + ONE shared attn+mlp block applied every
-                     ``hybrid_period`` layers (weights reused; separate KV
-                     cache per application)
+  hybrid (zamba2)    mamba2 backbone; before each layer of
+                     ``cfg.app_layers`` a shared transformer block runs over
+                     concat(x, x0) (x0 the token embedding), the
+                     applications alternating between ``num_mem_blocks``
+                     blocks; each application has its own MLP adapter,
+                     output linear and KV cache, and its output T enters
+                     its layer as x + mamba(norm(x + T))
   vlm                dense LM consuming stub patch embeddings prepended to text
   audio (whisper)    encoder (bidirectional) + decoder (self + cross attention)
 
@@ -141,7 +145,7 @@ def init_model(cfg, key, dtype=jnp.float32) -> Dict[str, Any]:
     elif cfg.family == "hybrid":
         params["layers"] = jax.vmap(
             lambda k: init_ssm_layer(cfg, k, dtype))(lkeys)
-        params["shared"] = init_decoder_layer(cfg, ks[3], dtype)
+        params.update(init_hybrid_params(cfg, ks[3], dtype))
     elif cfg.family == "audio":
         params["layers"] = jax.vmap(
             lambda k: init_decoder_layer(cfg, k, dtype, cross=True))(lkeys)
@@ -157,6 +161,92 @@ def init_model(cfg, key, dtype=jnp.float32) -> Dict[str, Any]:
         params["vision_proj"] = jax.random.normal(
             ks[5], (cfg.d_model, cfg.d_model), dtype) * 0.02
     return params
+
+
+def init_hybrid_params(cfg, key, dtype):
+    """Hybrid: the shared blocks, stacked over ``num_mem_blocks``
+    (``shared``), and each application's MLP adapter and output linear,
+    stacked over applications (``apps``)."""
+    d, F, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    w = cfg.num_heads * cfg.head_dim
+    std = 0.02
+
+    def block(k):
+        ks = jax.random.split(k, 6)
+        return {"ln1": {"scale": jnp.ones((2 * d,), dtype)},
+                "attn": {"wq": jax.random.normal(ks[0], (2 * d, w), dtype) * std,
+                         "wk": jax.random.normal(ks[1], (2 * d, w), dtype) * std,
+                         "wv": jax.random.normal(ks[2], (2 * d, w), dtype) * std,
+                         "wo": jax.random.normal(ks[3], (w, d), dtype) * std},
+                "ln2": {"scale": jnp.ones((d,), dtype)},
+                "mlp": {"w_gate_up": jax.random.normal(
+                            ks[4], (d, 2 * F), dtype) * std,
+                        "w_down": jax.random.normal(ks[5], (F, d), dtype) * std}}
+
+    def app(k):
+        ks = jax.random.split(k, 3)
+        return {"adapter_down": jax.random.normal(ks[0], (d, r), dtype) * std,
+                "adapter_up": jax.random.normal(ks[1], (r, 2 * F), dtype) * std,
+                "linear": jax.random.normal(ks[2], (d, d), dtype) * std}
+
+    kb, ka = jax.random.split(key)
+    return {"shared": jax.vmap(block)(jax.random.split(kb, cfg.num_mem_blocks)),
+            "apps": jax.vmap(app)(jax.random.split(ka, len(cfg.app_layers)))}
+
+
+def hybrid_block(cfg, params, g, x, x0, rope_cs, attend):
+    """Application ``g`` of the shared transformer block (Zamba2): block
+    ``g mod num_mem_blocks`` over concat(x, x0) -- RMSNorm, MHA with
+    rope and scores over sqrt(head_dim / 2), RMSNorm, gated GELU MLP with
+    the application's LoRA on gate_up -- then the application's linear.
+    ``attend(q, k, v) -> (out, state)`` runs the attention over the
+    roped heads (full sequence or against a cache).  Returns (T, state).
+    Weights are read through static indices: no copy of a block."""
+    p = jax.tree.map(lambda a: a[g % cfg.num_mem_blocks], params["shared"])
+    ap = jax.tree.map(lambda a: a[g], params["apps"])
+    B, S, _ = x.shape
+    H, hd = cfg.num_heads, cfg.head_dim
+    h = Lyr.rms_norm(jnp.concatenate([x, x0.astype(x.dtype)], -1),
+                     p["ln1"]["scale"], cfg.norm_eps)
+    a = p["attn"]
+    q = (h @ a["wq"]).reshape(B, S, H, hd)
+    k = (h @ a["wk"]).reshape(B, S, H, hd)
+    v = (h @ a["wv"]).reshape(B, S, H, hd)
+    cos, sin = rope_cs
+    # the kernels scale scores by 1/sqrt(hd); Zamba2 by 1/sqrt(hd / 2)
+    q = Lyr.apply_rope(q, cos, sin) * np.sqrt(2.0).astype(q.dtype)
+    k = Lyr.apply_rope(k, cos, sin)
+    att, state = attend(q, k, v)
+    o = att.reshape(B, S, H * hd) @ a["wo"]
+    h2 = Lyr.rms_norm(o, p["ln2"]["scale"], cfg.norm_eps)
+    gu = h2 @ p["mlp"]["w_gate_up"] \
+        + (h2 @ ap["adapter_down"]) @ ap["adapter_up"]
+    gate, up = jnp.split(gu, 2, axis=-1)
+    y = (jax.nn.gelu(gate, approximate=False) * up) @ p["mlp"]["w_down"]
+    return y @ ap["linear"], state
+
+
+def hybrid_layer(cfg, lp, x, t, cache=None, *, impl="jnp"):
+    """A Mamba-2 layer of the hybrid: x + mamba(norm(x + t)), ``t`` the
+    output of the application before it, or None."""
+    h = _apply_norm(cfg, lp["ln"], x if t is None else x + t)
+    y, new = SSM.mamba2_block(lp["mamba"], h, cache, cfg=cfg, impl=impl)
+    return x + y, new
+
+
+def hybrid_segments(cfg, lo: int, hi: int):
+    """Layers [lo, hi) as ``("mamba", a, b)`` runs of plain Mamba layers
+    and ``("app", i, g)`` for layer ``i`` preceded by application ``g``."""
+    apps = {i: g for g, i in enumerate(cfg.app_layers)}
+    segs = []
+    for i in range(lo, hi):
+        if i in apps:
+            segs.append(("app", i, apps[i]))
+        elif segs and segs[-1][0] == "mamba" and segs[-1][2] == i:
+            segs[-1] = ("mamba", segs[-1][1], i + 1)
+        else:
+            segs.append(("mamba", i, i + 1))
+    return segs
 
 
 # ---------------------------------------------------------------------------
@@ -322,44 +412,38 @@ def forward_hidden(cfg, params, inputs, *, attn_impl="chunked", window=None,
         kv_tree = caches if collect_kv else None
 
     elif cfg.family == "hybrid":
-        period = cfg.hybrid_period
-        n_apps = cfg.num_layers // period
-        attn_kvs = []
-        mamba_caches = []
-
         from repro.distributed import policy as pol
+        x0 = x
+        attn_kvs, mamba_caches = [], []
 
-        def mamba_body(carry, lp):
-            x, aux = carry
-            h = _apply_norm(cfg, lp["ln"], x)
-            y, cache = SSM.mamba2_block(lp["mamba"], h, cfg=cfg)
-            return (pol.constrain_hidden(x + y), aux), cache if collect_kv else None
+        def mamba_body(x, lp):
+            x, cache = hybrid_layer(cfg, lp, x, None)
+            return pol.constrain_hidden(x), cache if collect_kv else None
         mbody = jax.checkpoint(mamba_body) if remat else mamba_body
 
-        def run_group(x, aux, lo, hi):
-            lp = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-            (x, aux), caches = jax.lax.scan(mbody, (x, aux), lp)
+        def attend(q, k, v):
+            return Lyr.attention(q, k, v, causal=True, window=window,
+                                 impl=attn_impl), (k, v)
+
+        for kind, i, j in hybrid_segments(cfg, 0, cfg.num_layers):
+            if kind == "mamba":
+                lp = jax.tree.map(lambda a: a[i:j], params["layers"])
+                x, caches = jax.lax.scan(mbody, x, lp)
+            else:
+                t, kv = hybrid_block(cfg, params, j, x, x0, rope_cs, attend)
+                lp = jax.tree.map(lambda a: a[i], params["layers"])
+                x, cache = hybrid_layer(cfg, lp, x, t)
+                x = pol.constrain_hidden(x)
+                caches = jax.tree.map(lambda a: a[None], cache)
+                attn_kvs.append(kv)
             if collect_kv:
                 mamba_caches.append(caches)
-            return x, aux
-
-        for g in range(n_apps):
-            x, aux = run_group(x, aux, g * period, (g + 1) * period)
-            x, kv, a = attn_block_full(cfg, params["shared"], x, rope_cs,
-                                       impl=attn_impl, window=window)
-            aux = aux + a
-            if collect_kv:
-                attn_kvs.append(kv)
-        if n_apps * period < cfg.num_layers:
-            x, aux = run_group(x, aux, n_apps * period, cfg.num_layers)
         kv_tree = None
         if collect_kv:
             mcat = jax.tree.map(lambda *a: jnp.concatenate(a, 0), *mamba_caches)
-            kv_tree = {
-                "mamba": mcat,
-                "attn": {"k": jnp.stack([kv[0] for kv in attn_kvs]),
-                         "v": jnp.stack([kv[1] for kv in attn_kvs])},
-            }
+            kv_tree = {"mamba": mcat,
+                       "attn": {"k": jnp.stack([kv[0] for kv in attn_kvs]),
+                                "v": jnp.stack([kv[1] for kv in attn_kvs])}}
 
     elif cfg.family == "audio":
         enc_out = encode_audio(cfg, params, inputs["frames"],
@@ -492,8 +576,8 @@ def init_cache(cfg, batch, max_seq, dtype=jnp.float32, window=None):
     if cfg.family == "hybrid":
         s = cfg.ssm
         H = cfg.d_inner // s.head_dim
-        n_apps = cfg.num_layers // cfg.hybrid_period
-        conv_dim = cfg.d_inner + 2 * s.d_state
+        n_apps = len(cfg.app_layers)
+        conv_dim = cfg.d_inner + 2 * s.n_groups * s.d_state
         kv = jnp.zeros((n_apps, batch, KH, cl, hd), dtype)
         return {"mamba": {"conv": jnp.zeros((L, batch, s.d_conv - 1, conv_dim), dtype),
                           "ssm": jnp.zeros((L, batch, H, s.head_dim, s.d_state), jnp.float32)},
@@ -511,25 +595,15 @@ def init_cache(cfg, batch, max_seq, dtype=jnp.float32, window=None):
 # decode
 # ---------------------------------------------------------------------------
 
-def _attn_decode_sublayer(cfg, p, x, k_all, v_all, li, pos, *, window,
-                          impl="chunked"):
-    """One-token self-attn against the STACKED heads-major cache.
-
-    k/v_all: (L, B, KH, CL, hd); li: layer index (traced or static).
+def _ring_attend(q, k, v, k_all, v_all, li, pos, *, impl):
+    """Write one token's K/V into layer ``li`` of the STACKED heads-major
+    ring cache (L, B, KH, CL, hd) and attend against it.
 
     The caches stay scan CARRIES and only the (1, B, KH, 1, hd) token slice
     is written — returning per-layer caches as scan ys makes XLA copy the
     whole layer cache every step (measured 2x67 MB/layer/device on
     yi-34b decode_32k, 32x the roofline minimum).
-    """
-    B = x.shape[0]
-    h = _apply_norm(cfg, p["ln1"], x)
-    q, k, v = _project_qkv(cfg, p["attn"], h)
-    cos, sin = Lyr.rope_cos_sin(pos[None], cfg.head_dim, cfg.rope_theta) \
-        if cfg.family != "audio" else (None, None)
-    if cos is not None:
-        q = Lyr.apply_rope(q, cos[None], sin[None])
-        k = Lyr.apply_rope(k, cos[None], sin[None])
+    Returns (att, k_all, v_all)."""
     CL = k_all.shape[3]
     widx = jnp.mod(pos, CL)                       # ring write index
     li = jnp.asarray(li, jnp.int32)
@@ -556,6 +630,24 @@ def _attn_decode_sublayer(cfg, p, x, k_all, v_all, li, pos, *, window,
     else:
         att = Lyr.decode_attention(q, k_layer, v_layer, pos=eff_pos,
                                    window=None)
+    return att, k_all, v_all
+
+
+def _attn_decode_sublayer(cfg, p, x, k_all, v_all, li, pos, *, window,
+                          impl="chunked"):
+    """One-token self-attn against the STACKED heads-major cache.
+
+    k/v_all: (L, B, KH, CL, hd); li: layer index (traced or static)."""
+    B = x.shape[0]
+    h = _apply_norm(cfg, p["ln1"], x)
+    q, k, v = _project_qkv(cfg, p["attn"], h)
+    cos, sin = Lyr.rope_cos_sin(pos[None], cfg.head_dim, cfg.rope_theta) \
+        if cfg.family != "audio" else (None, None)
+    if cos is not None:
+        q = Lyr.apply_rope(q, cos[None], sin[None])
+        k = Lyr.apply_rope(k, cos[None], sin[None])
+    att, k_all, v_all = _ring_attend(q, k, v, k_all, v_all, li, pos,
+                                     impl=impl)
     x = x + att.reshape(B, 1, -1) @ p["attn"]["wo"]
     h2 = _apply_norm(cfg, p["ln2"], x)
     if "moe" in p:
@@ -598,35 +690,36 @@ def decode_step(cfg, params, token, cache, *, window=None, attn_impl="chunked"):
         new_cache = {"mamba": {"conv": convs, "ssm": hs}, "pos": pos + 1}
 
     elif cfg.family == "hybrid":
-        period = cfg.hybrid_period
-        n_apps = cfg.num_layers // period
+        x0 = x
+        rope_cs = Lyr.rope_cos_sin(pos[None], cfg.head_dim, cfg.rope_theta)
+        conv_all, ssm_all = cache["mamba"]["conv"], cache["mamba"]["ssm"]
+        k_all, v_all = cache["attn"]["k"], cache["attn"]["v"]
 
         def mbody(x, xs):
             lp, conv, hssm = xs
-            h = _apply_norm(cfg, lp["ln"], x)
-            y, nc = SSM.mamba2_block(lp["mamba"], h,
-                                     cache={"conv": conv, "ssm": hssm}, cfg=cfg)
-            return x + y, (nc["conv"], nc["ssm"])
+            x, nc = hybrid_layer(cfg, lp, x, None,
+                                 {"conv": conv, "ssm": hssm})
+            return x, (nc["conv"], nc["ssm"])
 
         convs_out, hs_out = [], []
-        k_all, v_all = cache["attn"]["k"], cache["attn"]["v"]
-
-        def run_group(x, lo, hi):
-            lp = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-            conv = cache["mamba"]["conv"][lo:hi]
-            hssm = cache["mamba"]["ssm"][lo:hi]
-            x, (nconv, nh) = jax.lax.scan(mbody, x, (lp, conv, hssm))
+        for kind, i, j in hybrid_segments(cfg, 0, cfg.num_layers):
+            if kind == "mamba":
+                lp = jax.tree.map(lambda a: a[i:j], params["layers"])
+                x, (nconv, nh) = jax.lax.scan(
+                    mbody, x, (lp, conv_all[i:j], ssm_all[i:j]))
+            else:
+                def attend(q, k, v, g=j):
+                    nonlocal k_all, v_all
+                    att, k_all, v_all = _ring_attend(
+                        q, k, v, k_all, v_all, g, pos, impl=attn_impl)
+                    return att, None
+                t, _ = hybrid_block(cfg, params, j, x, x0, rope_cs, attend)
+                lp = jax.tree.map(lambda a: a[i], params["layers"])
+                x, nc = hybrid_layer(cfg, lp, x, t,
+                                     {"conv": conv_all[i], "ssm": ssm_all[i]})
+                nconv, nh = nc["conv"][None], nc["ssm"][None]
             convs_out.append(nconv)
             hs_out.append(nh)
-            return x
-
-        for g in range(n_apps):
-            x = run_group(x, g * period, (g + 1) * period)
-            x, k_all, v_all = _attn_decode_sublayer(
-                cfg, params["shared"], x, k_all, v_all, g, pos,
-                window=window, impl=attn_impl)
-        if n_apps * period < cfg.num_layers:
-            x = run_group(x, n_apps * period, cfg.num_layers)
         new_cache = {
             "mamba": {"conv": jnp.concatenate(convs_out, 0),
                       "ssm": jnp.concatenate(hs_out, 0)},

@@ -19,7 +19,7 @@ entries into a **slot pool**:
   and scatters the resulting row state into a free slot while the other
   slots keep decoding.
 * **LRU / preemption eviction** — live per-slot state is priced with
-  ``state_handoff.per_layer_state_bytes`` against ``mem_budget_bytes``
+  ``state_handoff.range_state_bytes`` against ``mem_budget_bytes``
   (the same accounting the pipeline pool uses for standby weights);
   over-budget admission parks the least-recently-used slot's state as a
   serialized payload that ``readmit`` restores bit-exactly later.
@@ -57,10 +57,10 @@ from repro.core.concurrency import (RANK_SESSION_MANAGER, guarded_by,
                                     make_lock)
 from repro.core.hardware import CLOUD_SPEC
 from repro.core.network import NetworkModel
-from repro.core.state_handoff import per_layer_state_bytes
+from repro.core.state_handoff import range_state_bytes
 from repro.core.stateful import (HANDOFF_META_KEY, StatefulStageRunner,
-                                 _unit_state_keys, payload_checksum,
-                                 unit_index_of_split)
+                                 decode_payload, export_state, import_state,
+                                 is_kv, payload_checksum, state_keys)
 from repro.models import transformer as T
 
 
@@ -168,11 +168,8 @@ class SessionManager:
     def subset(self, u0: int, u1: int) -> Dict[str, Any]:
         """The slot-pool state entries a stage over units [u0, u1) sees."""
         with self._lock:
-            out = {}
-            for unit in self.runner.units[u0:u1]:
-                for k in _unit_state_keys(self.cfg, unit):
-                    out[k] = self.cache[k]
-            return out
+            return {k: self.cache[k] for i in range(u0, u1)
+                    for k in state_keys(self.cfg, i)}
 
     def commit_step(self, token, new_state: Dict[str, Any], bounds,
                     logits) -> None:
@@ -276,11 +273,11 @@ class SessionManager:
     # -- memory accounting / eviction -------------------------------------
     def slot_state_bytes(self, pos: int) -> int:
         """Priced bytes of one slot's live state at context length
-        ``pos`` — the same ``per_layer_state_bytes`` pricing the hand-off
-        planner uses (f32 state, one batch row, every unit)."""
-        return per_layer_state_bytes(
-            self.cfg, seq_len=max(int(pos), 1), batch=1, act_bytes=4) \
-            * len(self.runner.units)
+        ``pos`` — the same ``range_state_bytes`` pricing the hand-off
+        planner uses (f32 state, one batch row, every layer)."""
+        return range_state_bytes(self.cfg, 0, self.cfg.num_layers,
+                                 seq_len=max(int(pos), 1), batch=1,
+                                 act_bytes=4)
 
     def state_bytes(self) -> int:
         """Priced bytes of all live slots' state."""
@@ -323,10 +320,10 @@ class SessionManager:
     def _park_slot(self, j: int) -> None:    # holds: _lock
         slot = self._slots[j]
         state: Dict[str, tuple] = {}
-        for unit in self.runner.units:
-            for k in _unit_state_keys(self.cfg, unit):
+        for i in self.runner.units:
+            for k in state_keys(self.cfg, i):
                 arr = timing.fetch(self.cache[k][j])
-                if k[0] in ("k", "v", "a"):      # row KV: (KH, S, hd)
+                if is_kv(k):                     # row KV: (KH, S, hd)
                     arr = arr[:, :slot.pos]
                 state[k] = (str(arr.dtype), arr.shape, arr.tobytes())
         self._parked[slot.sid] = {
@@ -355,7 +352,7 @@ class SessionManager:
             pos = parked["pos"]
             for k, (dtype, shape, buf) in parked["state"].items():
                 arr = np.frombuffer(buf, dtype=dtype).reshape(shape)
-                if k[0] in ("k", "v", "a"):
+                if is_kv(k):
                     full = np.zeros(self.cache[k].shape[1:], arr.dtype)
                     full[:, :arr.shape[1]] = arr
                     arr = full
@@ -398,20 +395,11 @@ class SessionManager:
         batch axis intact, KV sliced to the max live prefix (rows are
         zero beyond their own pos, so nothing is lost).  Same envelope
         (epoch, pos, crc) and wire format as ``DecodeSession``."""
-        u0 = unit_index_of_split(self.cfg, lo)
-        u1 = unit_index_of_split(self.cfg, hi)
-        payload: Dict[str, tuple] = {}
-        nbytes = 0
         with self._lock:
             pos = max((s.pos for s in self._slots if s.live), default=0)
-            for unit in self.runner.units[u0:u1]:
-                for k in _unit_state_keys(self.cfg, unit):
-                    arr = timing.fetch(self.cache[k])
-                    if k[0] in ("k", "v", "a"):
-                        arr = arr[:, :, :pos]
-                    buf = arr.tobytes()
-                    payload[k] = (str(arr.dtype), arr.shape, buf)
-                    nbytes += len(buf)
+            payload, nbytes = export_state(
+                self.cache, [k for i in range(lo, hi)
+                             for k in state_keys(self.cfg, i)], pos)
             payload[HANDOFF_META_KEY] = (self.epoch, pos,
                                          payload_checksum(payload))
         return payload, nbytes
@@ -436,42 +424,26 @@ class SessionManager:
         """Deserialize a batch export back into the pool; validates and
         fully decodes BEFORE committing (corruption leaves the pool
         pristine for the recompute fallback)."""
-        from repro.core.stateful import HandoffCorrupted
         self.validate_payload(payload)
-        decoded: Dict[str, np.ndarray] = {}
-        try:
-            for k, (dtype, shape, buf) in payload.items():
-                if k == HANDOFF_META_KEY:
-                    continue
-                decoded[k] = np.frombuffer(buf, dtype=dtype).reshape(shape)
-        except (ValueError, TypeError) as e:
-            raise HandoffCorrupted(f"undecodable hand-off entry "
-                                   f"{k!r}: {e}") from None
+        decoded = decode_payload(payload)
         with self._lock:
-            for k, arr in decoded.items():
-                if k[0] in ("k", "v", "a"):
-                    full = np.zeros(self.cache[k].shape, arr.dtype)
-                    full[:, :, :arr.shape[2]] = arr
-                    self.cache[k] = timing.upload(full)
-                else:
-                    self.cache[k] = timing.upload(arr)
+            import_state(self.cache, decoded)
 
     def recompute_layers(self, lo: int, hi: int) -> None:
         """Rebuild layers [lo, hi) for EVERY slot from the per-slot
         boundary checkpoints: one masked fixed-shape pass with a
         ``(num_slots,)`` length vector — dead slots (length 0) rebuild to
         zero state, live slots to their exact pre-handoff state."""
-        u0 = unit_index_of_split(self.cfg, lo)
-        u1 = unit_index_of_split(self.cfg, hi)
-        if u0 >= u1:
+        if lo >= hi:
             return
         r = self.runner
-        fn = r.recompute_fn(u0, u1)          # runner lock first (42 < 47)
+        fn = r.recompute_fn(lo, hi)          # runner lock first (42 < 47)
         with self._lock:
-            x0 = timing.upload(self.bounds[u0])          # (B, max_seq, D)
+            x = timing.upload(self.bounds[lo])           # (B, max_seq, D)
+            tokens = timing.upload(self.tokens)
             lengths = timing.upload(
                 np.asarray([s.pos for s in self._slots], np.int32))
-        caches = fn(r.params, x0, lengths)
+        caches = fn(r.params, x, tokens, lengths)
         timing.block(caches)
         with self._lock:
             self.cache.update(caches)
@@ -491,9 +463,9 @@ class SessionManager:
             decode = r._make_decode_fn(0, U)
 
             def step(params, tok, cache, pos):
-                x = params["embed"][tok]
+                x = r.stream(params["embed"][tok])
                 x, new, b = decode(params, x, cache, pos)
-                h = T._apply_norm(cfg, params["final_norm"], x)
+                h = T._apply_norm(cfg, params["final_norm"], r.hidden(x))
                 logits = (h[:, -1] @ T.lm_head_weights(cfg, params)) \
                     .astype(jnp.float32)
                 return logits, new, b

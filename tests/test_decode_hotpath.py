@@ -2,9 +2,9 @@
 
 The serving decode path has two orthogonal knobs on
 ``StatefulStageRunner`` — ``rolled`` (lax.scan over stacked per-layer
-weights for mamba decode spans and full-sequence ranges vs the unrolled
-Python-loop trace; attention decode ranges read each layer through a
-static index either way) and ``decode_impl``
+weights for full-sequence ranges vs the unrolled Python-loop trace;
+decode ranges read each layer through a static index either way) and
+``decode_impl``
 (``flash_decode``/``mamba_scan``/``ssd_scan`` Pallas kernels vs the XLA
 reference ops).  Every combination must produce the same logits AND the
 same exported hand-off state layout, for all four families (plus a GQA
@@ -54,11 +54,12 @@ def _run_path(cfg, params, *, decode_impl, rolled):
     s.prefill(toks)
     U = len(r.units)
     mid = U // 2
-    av = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype)
+    av = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype), t)
     logits = [np.asarray(s.last_logits)]
     for _ in range(STEPS):
         tok = s.next_token()
-        x = r.params["embed"][jnp.asarray(tok, jnp.int32)]
+        x = r.stream(r.params["embed"][jnp.asarray(tok, jnp.int32)])
         pos = jnp.int32(s.pos)
         fe = r.executable("decode", 0, mid, r.params, av(x),
                           s.subset(0, mid), av(pos))
@@ -66,7 +67,7 @@ def _run_path(cfg, params, *, decode_impl, rolled):
                           s.subset(mid, U), av(pos))
         xe, ne, be = fe(r.params, x, s.subset(0, mid), pos)
         xc, nc, bc = fc(r.params, xe, s.subset(mid, U), pos)
-        lg = (T._apply_norm(cfg, r.params["final_norm"], xc)[:, -1]
+        lg = (T._apply_norm(cfg, r.params["final_norm"], r.hidden(xc))[:, -1]
               @ T.lm_head_weights(cfg, r.params)).astype(jnp.float32)
         s.commit_step(tok, {**ne, **nc}, jnp.concatenate([be, bc], 0), lg)
         logits.append(np.asarray(lg))

@@ -286,7 +286,8 @@ def test_switch_b2_handoff_spans(rec, lm, mode, spans):
     timing.clear()
     rep = mgr.repartition("switch_b2", 2)
     recs = timing.records()
-    got = [r for r in recs if r.name.startswith("handoff.")]
+    got = [r for r in recs if r.name in ("handoff.export", "handoff.import",
+                                         "handoff.recompute")]
     assert [r.name for r in got] == spans
     assert all(r.attrs["layers"] == 1 and r.attrs["mode"] == mode
                for r in got)
@@ -298,9 +299,19 @@ def test_switch_b2_handoff_spans(rec, lm, mode, spans):
     assert h.t_wall == walls
     assert rep.t_handoff == h.t_wall + h.t_network
     assert rep.t_build == one("pool.build").wall
+    # an attention model's state is KV alone: one child span per arm
+    # part, and the KV bytes counted (serialized, or rebuilt)
+    kids = {r.name: r for r in recs if r.parent in {g.id for g in got}}
     if mode == "transfer":
-        assert got[0].attrs["d2h_bytes"] >= rep.handoff_bytes > 0
-        assert got[1].attrs["h2d_bytes"] > 0
+        assert set(kids) == {"handoff.export.kv", "handoff.import.kv"}
+        ex = kids["handoff.export.kv"]
+        assert ex.attrs["handoff_bytes.kv"] == rep.handoff_bytes > 0
+        assert ex.attrs["d2h_bytes"] >= rep.handoff_bytes
+        assert kids["handoff.import.kv"].attrs["h2d_bytes"] > 0
+    else:
+        assert not kids
+        assert got[0].attrs["handoff_bytes.kv"] > 0
+        assert "handoff_bytes.ssm" not in got[0].attrs
     mgr.close()
 
 

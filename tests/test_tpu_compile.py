@@ -90,11 +90,12 @@ def test_mamba1_scan_falcon_mamba_7b(one_chip, S):
 
 @pytest.mark.parametrize("S", [1, 1024])
 def test_ssd_scan_zamba2_7b(one_chip, S):
-    B, H, P, N = 1, 112, 64, 64
+    """Zamba2's grouped layout: 112 heads read 2 groups of B and C."""
+    B, H, G, P, N = 4, 112, 2, 64, 64
     sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                              sharding=one_chip)
     _compile(functools.partial(ssd_scan, interpret=False),
-             sd(B, S, H), sd(B, S, N), sd(B, S, N), sd(B, S, H, P),
+             sd(B, S, H), sd(B, S, G, N), sd(B, S, G, N), sd(B, S, H, P),
              sd(H), sd(B, H, P, N))
 
 
@@ -154,3 +155,39 @@ def test_decode_range_reads_f32_weights_per_layer(one_chip, on_tpu, lo, hi):
     assert "tpu_custom_call" in text
     assert f"bf16[{hi - lo}," not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+
+
+@pytest.mark.parametrize("lo,hi", [(0, 12), (12, 24), (0, 6), (6, 24)])
+def test_hybrid_decode_range_reads_f32_weights_per_layer(one_chip, on_tpu,
+                                                         lo, hi):
+    """The zamba2-7b benchmark cut's decode ranges (24 layers, splits 12
+    and 6) at 4 x 512 and published widths: the Mamba-2 layers and the
+    shared-block applications read their float32 weights per layer, so
+    no bf16 copy of several layers' in_proj is made (a lax.scan over a
+    run of Mamba layers made one: 1.0 GB of temporaries for 6 layers,
+    2.2 GB for layers 6-24) and the temporaries stay under 1 GiB."""
+    import re
+
+    from repro.core.stateful import state_keys
+    cfg = dataclasses.replace(get_config("zamba2-7b"), num_layers=24)
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    params = jax.tree.map(lambda a: sd(a.shape, a.dtype),
+                          jax.eval_shape(functools.partial(T.init_model, cfg),
+                                         jax.random.PRNGKey(0)))
+    runner = StatefulStageRunner(cfg, params, max_seq=512,
+                                 decode_impl="kernel")
+    B, s = 4, cfg.ssm
+    conv = cfg.d_inner + 2 * s.n_groups * s.d_state
+    shapes = {"conv": (B, s.d_conv - 1, conv),
+              "ssm": (B, cfg.d_inner // s.head_dim, s.head_dim, s.d_state),
+              "a": (B, cfg.num_kv_heads, 512, cfg.head_dim)}
+    cache = {k: sd(shapes["a" if k[0] == "a" else k.rstrip("0123456789")])
+             for i in range(lo, hi) for k in state_keys(cfg, i)}
+    x = sd((B, 1, cfg.d_model))
+    compiled = runner.executable("decode", lo, hi, params, (x, x), cache,
+                                 sd((B,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"bf16\[([2-9]|\d\d+),3584,14704\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
