@@ -291,9 +291,30 @@ def fetch(x) -> np.ndarray:
     return a
 
 
+def fetch_all(tree):
+    """``jax.device_get`` of a pytree: every leaf's copy started before
+    any is waited on, so one host sync for all of them, and their bytes."""
+    out = jax.device_get(tree)
+    if _on:
+        count("host_sync")
+        count("d2h_bytes", sum(np.asarray(a).nbytes
+                               for a in jax.tree_util.tree_leaves(out)))
+    return out
+
+
 def upload(x, dtype=None):
     """``jnp.asarray`` of host data; its bytes counted."""
     a = jnp.asarray(x, dtype)
     if _on and not isinstance(x, jax.Array):
         count("h2d_bytes", a.nbytes)
     return a
+
+
+def upload_all(tree):
+    """``jax.device_put`` of a pytree of host arrays, in one call; their
+    bytes counted."""
+    out = jax.device_put(tree)
+    if _on:
+        count("h2d_bytes", sum(a.nbytes
+                               for a in jax.tree_util.tree_leaves(out)))
+    return out

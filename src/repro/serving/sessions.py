@@ -16,8 +16,8 @@ entries into a **slot pool**:
   family is excluded for exactly this reason.
 * **Mid-flight admission** — ``admit`` runs the runner's masked-prefill
   admission fn at a fixed ``(1, max_seq)`` bucket (one compile, ever)
-  and scatters the resulting row state into a free slot while the other
-  slots keep decoding.
+  and places the resulting row state into a free slot, in one program
+  over the whole pool, while the other slots keep decoding.
 * **LRU / preemption eviction** — live per-slot state is priced with
   ``state_handoff.range_state_bytes`` against ``mem_budget_bytes``
   (the same accounting the pipeline pool uses for standby weights);
@@ -62,6 +62,34 @@ from repro.core.stateful import (HANDOFF_META_KEY, StatefulStageRunner,
                                  decode_payload, export_state, import_state,
                                  is_kv, payload_checksum, state_keys)
 from repro.models import transformer as T
+
+
+# -- moves of one slot's rows across the whole state pool ------------------
+# Each is one compiled program over the pool's dict, with the slot index
+# traced: one compile serves every slot, and the pool's own keys (KV only,
+# or conv/SSM and KV) give each family its program.  Module-level, not in
+# the runner's compile caches, whose lock ranks below the manager's.
+
+@jax.jit
+def _place_rows(cache, rows, j):
+    """``cache`` with row ``j`` of each entry set to ``rows[k][0]``."""
+    return {k: jax.lax.dynamic_update_slice_in_dim(v, rows[k], j, 0)
+            for k, v in cache.items()}
+
+
+@jax.jit
+def _take_rows(cache, j):
+    """Row ``j`` of every entry."""
+    return {k: jax.lax.dynamic_index_in_dim(v, j, 0, keepdims=False)
+            for k, v in cache.items()}
+
+
+@jax.jit
+def _clear_rows(cache, j):
+    """``cache`` with row ``j`` of every entry zeroed."""
+    return {k: jax.lax.dynamic_update_slice_in_dim(
+        v, jnp.zeros((1,) + v.shape[1:], v.dtype), j, 0)
+        for k, v in cache.items()}
 
 
 class SlotPoolFull(RuntimeError):
@@ -214,9 +242,9 @@ class SessionManager:
                 # resolve the compiled admission fn BEFORE taking our lock:
                 # the runner's cache lock ranks below ours (42 < 47)
                 admit_f = r.admit_fn()
-                tok = np.zeros((1, self.max_seq), np.int32)
-                tok[0, :L] = prompt
-                tok = timing.upload(tok)
+                row = np.zeros((1, self.max_seq), np.int32)
+                row[0, :L] = prompt
+                tok = timing.upload(row)
                 logits, caches, bounds = admit_f(r.params, tok, jnp.int32(L))
                 timing.block(logits)
             if not self._calibrated:
@@ -229,11 +257,11 @@ class SessionManager:
             with timing.span("admit.place"), self._lock:
                 j = self._find_slot()
                 slot = self._slots[j]
-                for k, v in caches.items():
-                    self.cache[k] = self.cache[k].at[j].set(v[0])
-                self.bounds[:, j] = timing.fetch(bounds)[:, 0]
-                self.tokens[j] = timing.fetch(tok)[0]
-                self.last_logits[j] = timing.fetch(logits)[0]
+                self._place(caches, j)
+                b, lg = timing.fetch_all((bounds, logits))
+                self.bounds[:, j] = b[:, 0]
+                self.tokens[j] = row[0]
+                self.last_logits[j] = lg[0]
                 if sid is None:
                     sid = f"s{self._next_sid}"
                     self._next_sid += 1
@@ -317,12 +345,25 @@ class SessionManager:
         with timing.span("sessions.park", sid=self._slots[j].sid):
             self._park_slot(j)
 
+    def _place(self, rows: Dict[str, Any], j: int) -> None:  # holds: _lock
+        """Row ``j`` of the entries ``rows`` names set to ``rows[k][0]``,
+        in one program."""
+        self.cache.update(_place_rows({k: self.cache[k] for k in rows},
+                                      rows, np.int32(j)))
+        timing.count("slot_rows")
+
     def _park_slot(self, j: int) -> None:    # holds: _lock
         slot = self._slots[j]
+        # take and clear dispatch before the one fetch waits on the take
+        taken = _take_rows(self.cache, np.int32(j))
+        timing.count("slot_rows")
+        self.cache.update(_clear_rows(self.cache, np.int32(j)))
+        timing.count("slot_rows")
+        rows = timing.fetch_all(taken)
         state: Dict[str, tuple] = {}
         for i in self.runner.units:
             for k in state_keys(self.cfg, i):
-                arr = timing.fetch(self.cache[k][j])
+                arr = rows[k]
                 if is_kv(k):                     # row KV: (KH, S, hd)
                     arr = arr[:, :slot.pos]
                 state[k] = (str(arr.dtype), arr.shape, arr.tobytes())
@@ -333,8 +374,6 @@ class SessionManager:
             "logits": self.last_logits[j].copy(),
             "pos": slot.pos,
         }
-        for k in self.cache:
-            self.cache[k] = self.cache[k].at[j].set(0)
         self.tokens[j] = 0
         self.bounds[:, j] = 0
         self.last_logits[j] = 0
@@ -350,13 +389,15 @@ class SessionManager:
             parked = self._parked.pop(sid)
             slot = self._slots[j]
             pos = parked["pos"]
+            rows = {}
             for k, (dtype, shape, buf) in parked["state"].items():
                 arr = np.frombuffer(buf, dtype=dtype).reshape(shape)
                 if is_kv(k):
                     full = np.zeros(self.cache[k].shape[1:], arr.dtype)
                     full[:, :arr.shape[1]] = arr
                     arr = full
-                self.cache[k] = self.cache[k].at[j].set(timing.upload(arr))
+                rows[k] = arr[None]
+            self._place(timing.upload_all(rows), j)
             self.tokens[j, :pos] = parked["tokens"]
             self.bounds[:, j, :pos] = parked["bounds"]
             self.last_logits[j] = parked["logits"]

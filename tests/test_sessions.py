@@ -21,7 +21,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import (NetworkModel, make_stateful_manager,
                         per_layer_state_bytes)
-from repro.core.stateful import StatefulStageRunner
+from repro.core.stateful import StatefulStageRunner, is_kv
 from repro.models import transformer as T
 from repro.serving import (ServingEngine, SlotPoolFull, VirtualClock,
                            make_session_manager, request_stream)
@@ -90,6 +90,43 @@ def test_evict_readmit_round_trips_state(tf_runner):
     np.testing.assert_array_equal(sm.logits_for(a), before_logits)
     np.testing.assert_array_equal(sm.tokens_for(a), before_toks)
     sm.decode_step()                 # restored state still decodes
+    assert sm.slot_info(a).pos == before_toks.shape[0] + 1
+
+
+def test_evict_readmit_round_trips_hybrid_state():
+    """A conv/SSM + KV pool: the parked payload is byte for byte the
+    slot's rows (KV sliced to its prefix), every entry's row is zero
+    after the eviction, and readmission restores logits and tokens
+    bit-exactly."""
+    cfg = _cfg("zamba2-7b", num_layers=4)
+    params = T.init_model(cfg, jax.random.PRNGKey(0))
+    runner = StatefulStageRunner(cfg, params, max_seq=32)
+    pa, pb, pc = _ragged(cfg, (6, 4, 3), seed=2)
+    sm = SessionManager(runner, num_slots=3)
+    b, a = sm.admit(pb), sm.admit(pa)        # a in a slot other than 0
+    sm.decode_step()
+    assert {k[:2] for k in sm.cache} >= {"co", "ss", "ak", "av"}
+    j, pos = sm.slot_info(a).index, sm.slot_info(a).pos
+    assert j == 1
+    expect = {}
+    for k, v in sm.cache.items():
+        row = np.asarray(v)[j]
+        expect[k] = row[:, :pos] if is_kv(k) else row
+    before_logits, before_toks = sm.logits_for(a), sm.tokens_for(a)
+    sm.evict(a)
+    parked = sm._parked[a]["state"]
+    assert set(parked) == set(expect)
+    for k, row in expect.items():
+        dtype, shape, buf = parked[k]
+        assert (dtype, tuple(shape)) == (str(row.dtype), row.shape), k
+        assert buf == row.tobytes(), k
+        assert not np.asarray(sm.cache[k])[j].any(), k
+    sm.admit(pc)
+    sm.decode_step()
+    sm.readmit(a)
+    np.testing.assert_array_equal(sm.logits_for(a), before_logits)
+    np.testing.assert_array_equal(sm.tokens_for(a), before_toks)
+    sm.decode_step()
     assert sm.slot_info(a).pos == before_toks.shape[0] + 1
 
 

@@ -332,6 +332,31 @@ def test_admission_and_eviction_spans(rec, lm):
     mgr.close()
 
 
+def test_slot_rows_move_in_one_program_compiled_once(rec, lm):
+    """An admission places its slot's rows with one program and one host
+    sync; an ended session is parked with one take and one clear and one
+    fetch; other slot indices reuse the same compiles."""
+    CacheEvents()                       # the listener counting compiles
+    mgr, sm = _sessions(lm)
+    for place in named("admit.place"):
+        assert place.attrs["slot_rows"] == 1
+        assert place.attrs.get("host_sync", 0) <= 1
+    sm.evict(sm.session_ids()[0])
+    park = one("sessions.park")
+    assert park.attrs["slot_rows"] == 2 and park.attrs["host_sync"] == 1
+    assert park.attrs["d2h_bytes"] > 0
+    rng = np.random.default_rng(1)
+    with timing.span("reuse"):
+        for _ in range(3):              # a different free slot each time
+            sid = sm.session_ids()[0]
+            sm.admit(rng.integers(0, lm[0].vocab_size, 6).astype(np.int32))
+            sm.evict(sid)
+    reuse = one("reuse")
+    assert total(reuse, "backend_compile", timing.records()) == 0
+    assert total(reuse, "slot_rows", timing.records()) == 3 * (1 + 2)
+    mgr.close()
+
+
 # ---------------------------------------------------------------------------
 # the profiler's clock
 # ---------------------------------------------------------------------------
