@@ -1,62 +1,16 @@
 #!/usr/bin/env bash
-# Tiered CI driver.
+# Tier-1 CI gate, what every push runs.
 #
-#   ./ci.sh [--tier1] [extra pytest args]   fast gate (default):
-#       the whole pytest suite, fail-fast, suite-wide per-test timeout,
-#       then tests/test_sharding.py again in its own process under an
+#   ./ci.sh [extra pytest args]
+#       static analysis (repro.analysis, then ruff when installed), the
+#       whole pytest suite, fail-fast, suite-wide per-test timeout, then
+#       tests/test_sharding.py again in its own process under an
 #       8-fake-device CPU backend (the XLA flag must not leak into the
 #       main suite's numerics, see tests/test_sharding.py).
-#       This is the ROADMAP's tier-1 verify and what every push runs.
 #
-#   ./ci.sh --tier2 [extra pytest args]     scheduled scenario gate:
-#       tier-1, then the measured-stream smokes — the ServingEngine
-#       single-camera smoke, the {strategy x arrival x clients} scenario
-#       matrix (fatal: the paper's downtime ordering must hold under
-#       Poisson and bursty multi-client arrivals, and the slo_aware
-#       policy must fire a p99-driven repartition), the state-handoff
-#       benchmark (fatal: the stateful ssm downtime ordering
-#       pause_resume >> switch_b2 >> switch_a, the transfer/recompute
-#       crossover direction, and >=90% plan/measured best-arm agreement;
-#       refreshes BENCH_handoff.json), the chaos grid in --smoke mode
-#       (fatal: deterministic fault injection — switch_a keeps serving
-#       under build_fail(p=1) while pause_resume goes dark, stalled
-#       switches are watchdog-aborted + rolled back, link outages enter
-#       and exit edge-only degraded mode, corrupted hand-offs heal
-#       bit-exactly; refreshes BENCH_chaos.json), the serve_pipeline
-#       and serve_sessions examples in --smoke mode (examples stay
-#       executable, not rotting; serve_sessions additionally asserts a
-#       slot pool of concurrent sessions survives a mid-stream
-#       repartition with zero drops), the decode hot-path
-#       microbenchmark in --smoke mode
-#       (fatal: the kernel/rolled serving decode path must hold
-#       tokens/s vs the reference path and its cold range-build wall
-#       must stay within tol of the committed baseline; refreshes
-#       BENCH_decode.json), the sharded-cloud-stage microbenchmark in
-#       --smoke mode under an 8-fake-device CPU backend (fatal: every
-#       registered strategy must complete a mesh-shape-changing
-#       repartition with the resharding wall recorded on its report,
-#       and the per-mesh latency model must agree with the measured
-#       {mesh x split} cells; refreshes BENCH_shard.json), the
-#       switch-path microbenchmark (refreshes
-#       BENCH_switch.json; non-fatal: perf noise must not mask a green
-#       suite) and the perf-regression check against the committed
-#       baselines (BENCH_baseline.json + BENCH_handoff_baseline.json +
-#       BENCH_chaos_baseline.json + BENCH_decode_baseline.json +
-#       BENCH_shard_baseline.json; warns by default, BENCH_STRICT=1
-#       turns regressions fatal).
-#
-# Back-compat: SKIP_BENCH=1 forces tier-1 regardless of flags.
+# Speed is measured on the chip only: BENCHMARK.json and chipbench/.
 set -euo pipefail
 cd "$(dirname "$0")"
-
-TIER=1
-case "${1:-}" in
-    --tier1) TIER=1; shift ;;
-    --tier2) TIER=2; shift ;;
-esac
-if [[ "${SKIP_BENCH:-0}" == "1" ]]; then
-    TIER=1
-fi
 
 run_py() { PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python "$@"; }
 
@@ -80,44 +34,3 @@ run_py -m pytest -x -q "$@"
 # and run for real here
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     run_py -m pytest -x -q tests/test_sharding.py
-
-if [[ "$TIER" == "2" ]]; then
-    run_py -m repro.serving --smoke
-    run_py -m benchmarks.scenario_matrix --smoke
-    # drop the stale trajectory first: if the (fatal) refresh fails,
-    # check_regression must see a MISSING fresh file, not silently
-    # compare baseline against baseline
-    rm -f BENCH_handoff.json
-    run_py benchmarks/handoff.py --smoke
-    # chaos grid (fatal): the robustness story — fault injection is
-    # deterministic, hardened switching survives it
-    rm -f BENCH_chaos.json
-    run_py -m benchmarks.chaos --smoke
-    run_py examples/serve_pipeline.py --smoke
-    # multi-session slot-pool example (fatal: N concurrent sessions
-    # survive a mid-stream repartition with zero drops, per-session
-    # latency attribution prints)
-    run_py examples/serve_sessions.py --smoke
-    # decode hot-path gate (fatal): the serving decode path must not
-    # lose tokens/s to the reference path, and the rolled-range cold
-    # compile wall must stay within tol of the committed baseline;
-    # refreshes BENCH_decode.json (same staleness rule as above)
-    rm -f BENCH_decode.json
-    run_py benchmarks/decode_micro.py --smoke
-    # sharded cloud stage (fatal): mesh-changing repartitions must
-    # complete under every strategy with the resharding wall recorded,
-    # and the per-mesh latency model must track the measured cells; the
-    # benchmark forces its own 8-fake-device backend, the explicit env
-    # here just makes the CI contract visible
-    rm -f BENCH_shard.json
-    XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-        run_py benchmarks/shard_micro.py --smoke
-    # same staleness rule for the (non-fatal) switch microbenchmark
-    rm -f BENCH_switch.json
-    run_py benchmarks/switch_micro.py --smoke \
-        || echo "WARN: switch_micro smoke failed (non-fatal)" >&2
-    # warn-only by default; the scheduled workflow sets BENCH_STRICT=1
-    # (+ a cross-host BENCH_TOL) so regressions actually fail somewhere
-    # (covers BENCH_switch/handoff/chaos/decode vs committed baselines)
-    run_py benchmarks/check_regression.py --tol "${BENCH_TOL:-2.0}"
-fi

@@ -72,8 +72,8 @@ state handoff (stateful pipelines):
   over 1 Mbps dwarfs re-running the prefill).  Every SwitchReport then
   carries t_handoff (seconds the hand-off blocked the stream),
   handoff_bytes (really-serialized payload) and handoff_mode.  See
-  benchmarks/handoff.py for the measured crossover and the
-  stateful-vs-stateless downtime per strategy.
+  docs/serving.md for the hand-off's design; the chip benchmark
+  (BENCHMARK.json) measures it in its switch_b2 cells.
 """
 
 
@@ -89,10 +89,8 @@ def main():
     ap.add_argument("--hw", type=int, default=96,
                     help="input resolution (96 keeps it CPU-friendly)")
     ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: same model, compressed trace (2 live "
-                         "switches over 24 s instead of 90 s) so this "
-                         "example runs on every tier-2 pass instead of "
-                         "rotting untested")
+                    help="short mode: same model, compressed trace (2 "
+                         "live switches over 24 s instead of 90 s)")
     args = ap.parse_args()
     cfg = dataclasses.replace(get_config(args.arch), input_hw=args.hw)
     scratch = CnnStageRunner(cfg)

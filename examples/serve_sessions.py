@@ -25,7 +25,7 @@ from repro.serving import (ServingEngine, VirtualClock, make_session_manager,
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny run for CI: fewer sessions, shorter stream")
+                    help="tiny run: fewer sessions, shorter stream")
     ap.add_argument("--sessions", type=int, default=None,
                     help="total concurrent sessions (default 8, smoke 4)")
     args = ap.parse_args()

@@ -7,8 +7,7 @@ host partitions when <=10% memory is available).
 
 Complexity: ``ModelProfile.latency`` is O(1) via cached prefix sums, so
 ``latency_curve`` and ``optimal_split`` are O(n) in the number of units —
-cheap enough to re-solve on every network sample (the controller does),
-see ``benchmarks/switch_micro.py`` for the scaling measurement.
+cheap enough to re-solve on every network sample (the controller does).
 """
 from __future__ import annotations
 

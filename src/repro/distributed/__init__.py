@@ -10,9 +10,7 @@ walkable module for the collective/flops counters and the static checks.
 from repro.distributed import policy
 from repro.distributed.hlo_analysis import (Computation, HloModule, Instr,
                                             analyse_hlo_text)
-from repro.distributed.roofline import (KernelRoofline, Roofline,
-                                        collective_bytes, executable_cost,
-                                        kernel_roofline,
+from repro.distributed.roofline import (Roofline, collective_bytes,
                                         model_flops_estimate)
 from repro.distributed.sharding import (ShardingDegraded, batch_spec,
                                         cache_shardings,
@@ -29,8 +27,7 @@ __all__ = [
     # policy (module: set_policy/policy/choose_attn_mode/constrain_*)
     "policy",
     # roofline
-    "Roofline", "KernelRoofline", "kernel_roofline", "executable_cost",
-    "collective_bytes", "model_flops_estimate",
+    "Roofline", "collective_bytes", "model_flops_estimate",
     # hlo analysis
     "HloModule", "Computation", "Instr", "analyse_hlo_text",
 ]

@@ -15,7 +15,7 @@ bytes, for all-gather it upper-bounds the wire volume by n/(n-1).)
 from __future__ import annotations
 
 import re
-from dataclasses import asdict, dataclass, field
+from dataclasses import asdict, dataclass
 from typing import Dict, Optional
 
 from repro.core.hardware import ICI_LINK_BW, TPU_V5E
@@ -105,66 +105,6 @@ class Roofline:
 
     def to_dict(self):
         return asdict(self)
-
-
-@dataclass
-class KernelRoofline:
-    """Achieved vs roofline rates for ONE measured executable.
-
-    ``hlo_flops``/``hlo_bytes`` come from the compiled executable's
-    ``cost_analysis()``; ``wall_s`` is the measured per-call wall.  The
-    fractions compare achieved rates against a device spec's peaks —
-    decode is memory-bound (it streams the whole cache per token), so
-    ``bw_frac`` is the number that says how far the hot path sits from
-    the hardware floor."""
-    name: str
-    wall_s: float
-    hlo_flops: float
-    hlo_bytes: float
-    achieved_flops_per_s: float = 0.0
-    achieved_bytes_per_s: float = 0.0
-    flops_frac: float = 0.0
-    bw_frac: float = 0.0
-    bound: str = ""
-
-    def finish(self, spec=TPU_V5E):
-        if self.wall_s > 0:
-            self.achieved_flops_per_s = self.hlo_flops / self.wall_s
-            self.achieved_bytes_per_s = self.hlo_bytes / self.wall_s
-        self.flops_frac = self.achieved_flops_per_s / spec.flops
-        self.bw_frac = self.achieved_bytes_per_s / spec.hbm_bw
-        t_compute = self.hlo_flops / spec.flops
-        t_memory = self.hlo_bytes / spec.hbm_bw
-        self.bound = "memory" if t_memory >= t_compute else "compute"
-        return self
-
-    def to_dict(self):
-        return asdict(self)
-
-
-def executable_cost(compiled) -> Dict[str, float]:
-    """flops / bytes accessed of a compiled executable, robust to the
-    per-backend shape of ``cost_analysis()`` (dict or [dict])."""
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:            # backend without cost analysis
-        return {"flops": 0.0, "bytes accessed": 0.0}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return {"flops": float(cost.get("flops", 0.0) or 0.0),
-            "bytes accessed": float(cost.get("bytes accessed", 0.0) or 0.0)}
-
-
-def kernel_roofline(name: str, *, wall_s: float, compiled=None,
-                    cost: Optional[Dict[str, float]] = None,
-                    spec=TPU_V5E) -> KernelRoofline:
-    """Build a ``KernelRoofline`` from a measured wall plus either a
-    compiled executable or a pre-extracted ``executable_cost`` dict."""
-    if cost is None:
-        cost = executable_cost(compiled) if compiled is not None \
-            else {"flops": 0.0, "bytes accessed": 0.0}
-    return KernelRoofline(name, wall_s, cost.get("flops", 0.0),
-                          cost.get("bytes accessed", 0.0)).finish(spec)
 
 
 def model_flops_estimate(cfg, shape) -> float:

@@ -1,12 +1,13 @@
 """JAX's persistent compilation cache for this checkout.
 
 ``enable_compile_cache()`` is called by the entry points
-(``chip_smoke.py`` and the ``repro.launch`` mains) before their first
-compile, never at import.  Where ``JAX_COMPILATION_CACHE_DIR`` is set,
-JAX already uses that directory and no other is set here.  Otherwise the
-cache goes to ``<checkout>/.jax-cache``: a fixed path, because the path
-is what lets a later process find an entry; the same one CI uses; and
-listed in ``.gitignore``.
+(``chipbench/run.py``, ``chip_smoke.py`` and the ``repro.launch`` mains)
+before their first compile, never at import.  Where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that directory and
+no other is set here.  Otherwise the cache goes to
+``<checkout>/.jax-cache``: a fixed path, because the path is what lets a
+later process find an entry; the same one CI uses; and listed in
+``.gitignore``.
 
 With the cache on, a ``fresh=True`` ("new container") stage build still
 retraces and recompiles, but XLA's compile can be served from disk —

@@ -30,8 +30,8 @@ def make_cloud_mesh(shape):
 
     Works over whatever devices the process has — real accelerators in
     production, CPU fake devices in CI (run under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``; tests and
-    ``benchmarks/shard_micro.py`` arrange this).  Raises with an
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``; ``ci.sh``
+    arranges this for ``tests/test_sharding.py``).  Raises with an
     actionable message when the host has fewer devices than the shape
     needs, instead of letting ``jax.make_mesh`` fail obscurely.
     """

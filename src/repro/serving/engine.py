@@ -345,7 +345,7 @@ class ServingEngine:
     def schedule_network(self, t: float, bandwidth_mbps: float,
                          latency_ms: float = 20.0) -> None:
         """Script a link change at stream time ``t`` — the controller-less
-        path for driving outages through the breaker (chaos benchmarks)."""
+        path for driving outages through the breaker (the chaos grid)."""
         self._scheduled_net.append((t, bandwidth_mbps, latency_ms))
 
     # -- degraded mode (cloud link dead -> edge-only) -----------------------

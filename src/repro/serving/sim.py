@@ -1,6 +1,6 @@
-"""Analytic stand-in pipelines for the chaos benchmark grid.
+"""Analytic stand-in pipelines for the chaos grid.
 
-The chaos benchmark (``benchmarks/chaos.py``) sweeps {fault plan x
+The chaos grid (``tests/test_chaos.py``) sweeps {fault plan x
 strategy} cells; what it measures is the *control plane* — retries,
 watchdog aborts, degraded-mode transitions — not XLA compile times.
 ``SimPipeline`` therefore prices a request analytically (per-unit edge
@@ -8,7 +8,7 @@ and cloud seconds plus the real ``NetworkModel`` transfer price, so a
 dead link still returns ``inf``) and ``SimPool`` charges pipeline
 builds to an attached ``VirtualClock`` at a scripted cost instead of
 compiling anything.  Every number is deterministic, which is what lets
-the chaos smoke assert byte-identical timelines across runs.
+the chaos tests assert byte-identical timelines across runs.
 
 The fault-injection surface is the REAL one: ``SimPool`` inherits
 ``PipelinePool`` unchanged, so ``plan.on_build`` fires inside

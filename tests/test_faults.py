@@ -399,16 +399,25 @@ def test_corrupted_and_stale_payloads_rejected_state_untouched():
 
 
 def test_corrupt_handoff_falls_back_to_recompute():
-    mgr, session = _tiny_stateful(force_mode="transfer")
-    mgr.active.process()
-    mgr.pool.fault_plan = faults("handoff_corrupt(p=1.0)").arm()
-    with pytest.warns(HandoffIntegrityWarning):
-        mgr.repartition("switch_b2", 2)
-    h = mgr.pool.handoffs[-1]
-    assert h.fallback and h.mode == "recompute"
-    out, _ = mgr.active.process()            # recovered state still decodes
-    assert np.isfinite(np.asarray(out)).all()
-    mgr.close()
+    """A corrupted transfer heals through masked recompute, and the
+    served logits are bit-identical to a clean recompute run."""
+    logits = {}
+    for mode in ("recompute", "transfer"):
+        mgr, session = _tiny_stateful(force_mode=mode)
+        mgr.active.process()
+        if mode == "transfer":
+            mgr.pool.fault_plan = faults("handoff_corrupt(p=1.0)").arm()
+            with pytest.warns(HandoffIntegrityWarning):
+                mgr.repartition("switch_b2", 2)
+            assert mgr.pool.handoffs[-1].fallback
+        else:
+            mgr.repartition("switch_b2", 2)
+        assert mgr.pool.handoffs[-1].mode == "recompute"
+        out, _ = mgr.active.process()        # recovered state still decodes
+        logits[mode] = np.asarray(out)
+        mgr.close()
+    assert np.isfinite(logits["transfer"]).all()
+    assert np.array_equal(logits["transfer"], logits["recompute"])
 
 
 def test_corrupt_batch_handoff_falls_back_to_per_slot_recompute():
