@@ -1211,8 +1211,8 @@ class StatefulEdgeCloudPipeline:
         edge_sh = self._edge_sharding
         if edge_sh is not None and \
                 getattr(token, "sharding", None) != edge_sh:
-            # the previous step's logits (hence this argmax token) may be
-            # mesh-resident; the edge embed is compiled single-device
+            # a caller's token may live elsewhere; the edge embed is
+            # compiled single-device
             token = jax.device_put(token, edge_sh)
         with timing.span("step.edge", timed=True) as m:
             x = self.embed_fn(self.params, token)
@@ -1226,13 +1226,10 @@ class StatefulEdgeCloudPipeline:
             new_c, b_c, logits = self._run_cloud(xe, cache_cloud, pos)
         t_cloud = m.wall
         if self._repl is not None:
-            # mesh-resident and edge-resident bounds cannot mix in one
-            # jnp.concatenate (device mismatch); the session stores numpy
-            # anyway
-            bounds = np.concatenate([timing.fetch(b_e), timing.fetch(b_c)],
-                                    axis=0)
-        else:
-            bounds = jnp.concatenate([b_e, b_c], axis=0)
+            # the session commits the step where the edge stage lives: the
+            # mesh-resident bounds and logits move there, device to device
+            b_c, logits = jax.device_put((b_c, logits), edge_sh)
+        bounds = jnp.concatenate([b_e, b_c], axis=0)
         return logits, {**new_e, **new_c}, bounds, \
             RequestTiming(t_edge, t_transfer, t_cloud)
 

@@ -125,8 +125,8 @@ class BatchingServer:
         return {
             "cache": {k: np.asarray(v) for k, v in snap["cache"].items()},
             "tok": snap["tokens"],
-            "bounds": snap["bounds"],
-            "logits": snap["logits"],
+            "bounds": np.asarray(snap["bounds"]),
+            "logits": np.asarray(snap["logits"]),
             "slots": [(s.index, s.sid, s.pos, s.live, s.last_used, s.epoch)
                       for s in snap["slots"]],
             "parked": snap["parked"],

@@ -18,6 +18,10 @@ entries into a **slot pool**:
   admission fn at a fixed ``(1, max_seq)`` bucket (one compile, ever)
   and places the resulting row state into a free slot, in one program
   over the whole pool, while the other slots keep decoding.
+* **Step carry on the device** — each slot's boundary checkpoints and
+  last logits stay on the device, slot-major like the state; one
+  donated program per step writes the live rows and picks the next
+  token, so a step brings back only its ``(num_slots, 1)`` token.
 * **LRU / preemption eviction** — live per-slot state is priced with
   ``state_handoff.range_state_bytes`` against ``mem_budget_bytes``
   (the same accounting the pipeline pool uses for standby weights);
@@ -43,6 +47,7 @@ the lock to commit).  See ``docs/serving.md`` for the full architecture.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -65,16 +70,28 @@ from repro.models import transformer as T
 
 
 # -- moves of one slot's rows across the whole state pool ------------------
-# Each is one compiled program over the pool's dict, with the slot index
+# Each is one compiled program over the pool's state dict and its step
+# carry (the boundary checkpoints and last logits), with the slot index
 # traced: one compile serves every slot, and the pool's own keys (KV only,
 # or conv/SSM and KV) give each family its program.  Module-level, not in
-# the runner's compile caches, whose lock ranks below the manager's.
+# the runner's compile caches, whose lock ranks below the manager's.  The
+# carry is donated: only the manager holds it (``snapshot`` copies it), so
+# a move writes its rows in place instead of copying the whole buffer.
 
-@jax.jit
-def _place_rows(cache, rows, j):
-    """``cache`` with row ``j`` of each entry set to ``rows[k][0]``."""
-    return {k: jax.lax.dynamic_update_slice_in_dim(v, rows[k], j, 0)
-            for k, v in cache.items()}
+def _set_row(v, row, j):
+    """``v`` with row ``j`` set to ``row``, whose one slot axis of size 1
+    may sit before its data (an admission's checkpoints are
+    ``(U, 1, max_seq, D)``): the reshape moves no element."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        v, row.reshape((1,) + v.shape[1:]), j, 0)
+
+
+@functools.partial(jax.jit, donate_argnums=1)
+def _place_rows(cache, carry, rows, j):
+    """``cache`` and ``carry`` with row ``j`` of each entry set to
+    ``rows[k]``."""
+    return ({k: _set_row(v, rows[k], j) for k, v in cache.items()},
+            {k: _set_row(v, rows[k], j) for k, v in carry.items()})
 
 
 @jax.jit
@@ -84,12 +101,42 @@ def _take_rows(cache, j):
             for k, v in cache.items()}
 
 
-@jax.jit
-def _clear_rows(cache, j):
-    """``cache`` with row ``j`` of every entry zeroed."""
-    return {k: jax.lax.dynamic_update_slice_in_dim(
-        v, jnp.zeros((1,) + v.shape[1:], v.dtype), j, 0)
-        for k, v in cache.items()}
+@functools.partial(jax.jit, donate_argnums=1)
+def _clear_rows(cache, carry, j):
+    """``cache`` and ``carry`` with row ``j`` of every entry zeroed."""
+    def zero(v):
+        return jax.lax.dynamic_update_slice_in_dim(
+            v, jnp.zeros((1,) + v.shape[1:], v.dtype), j, 0)
+    return ({k: zero(v) for k, v in cache.items()},
+            {k: zero(v) for k, v in carry.items()})
+
+
+def _greedy(logits):
+    return jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+
+
+_greedy_token = jax.jit(_greedy)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _commit_rows(carry, bounds, logits, at):
+    """One decode step landed in the carry: each live row's ``(U, B, 1,
+    D)`` checkpoints written at its position ``at[b]`` and its logits
+    replaced; a dead row (``at[b]`` is ``max_seq``) keeps what it holds.
+    Returns the carry and the next ``(B, 1)`` token, the greedy pick of
+    the new logits.  One in-place slice update per row: a scatter over
+    the two row axes would transpose the whole buffer on a TPU."""
+    b = carry["bounds"]                              # (B, U, max_seq, D)
+    S = b.shape[2]
+    for r in range(b.shape[0]):
+        p = jnp.minimum(at[r], S - 1)
+        old = jax.lax.dynamic_slice(b, (r, 0, p, 0),
+                                    (1, b.shape[1], 1, b.shape[3]))
+        new = jnp.swapaxes(bounds[:, r:r + 1], 0, 1)
+        b = jax.lax.dynamic_update_slice(
+            b, jnp.where(at[r] < S, new, old), (r, 0, p, 0))
+    lg = jnp.where((at < S)[:, None], logits, carry["logits"])
+    return {"bounds": b, "logits": lg}, _greedy(lg)
 
 
 class SlotPoolFull(RuntimeError):
@@ -155,11 +202,14 @@ class SessionManager:
         self.cache: Dict[str, Any] = {
             k: jnp.zeros((B,) + v.shape[1:], v.dtype)
             for k, v in caches0.items()}
-        self.bounds = np.zeros(
-            (bounds0.shape[0], B) + tuple(bounds0.shape[2:]),
-            dtype=bounds0.dtype)            # (U, B, max_seq, D)
+        # the step carry, on the device and slot-major like the cache:
+        # every slot's boundary checkpoints and its last logits
+        self.carry: Dict[str, Any] = {
+            "bounds": jnp.zeros((B, bounds0.shape[0]) + bounds0.shape[2:],
+                                bounds0.dtype),   # (B, U, max_seq, D)
+            "logits": jnp.zeros((B, logits0.shape[-1]), logits0.dtype)}
+        self._next = None                   # greedy token of the carry
         self.tokens = np.zeros((B, self.max_seq), np.int32)
-        self.last_logits = np.zeros((B, logits0.shape[-1]), np.float32)
 
     # -- DecodeSession-compatible surface --------------------------------
     @property
@@ -183,10 +233,14 @@ class SessionManager:
         return timing.upload(pos)
 
     def next_token(self):
-        """Greedy next token per slot (dead rows produce garbage tokens
-        that only ever land in their own masked row)."""
-        return jnp.argmax(timing.upload(self.last_logits), -1)[:, None] \
-            .astype(jnp.int32)
+        """Greedy next token per slot, ``(num_slots, 1)`` on the device:
+        the one the last commit picked, or, after a slot move, the pick
+        of the carried logits (dead rows produce garbage tokens that only
+        ever land in their own masked row)."""
+        with self._lock:
+            if self._next is None:
+                self._next = _greedy_token(self.carry["logits"])
+            return self._next
 
     def handoff_net(self, net: NetworkModel) -> NetworkModel:
         """Slot pools skip the single-stream serialization calibration
@@ -204,23 +258,28 @@ class SessionManager:
         """Land one whole-batch decode step: state buffers swap to the
         new batch, but tokens/bounds/logits commit per LIVE slot only —
         dead rows' garbage never reaches the bookkeeping buffers, so the
-        zero-beyond-prefix invariant survives."""
+        zero-beyond-prefix invariant survives.  The bounds and logits stay
+        on the device (one program writes them into the carry and picks
+        the next token); only the step's ``(num_slots, 1)`` token comes
+        back, for the host's token history."""
         tok = timing.fetch(token)
-        b = timing.fetch(bounds)
-        lg = timing.fetch(logits)
         with self._lock:
-            self.cache.update(new_state)
-            self.epoch += 1
-            for slot in self._slots:
-                if not slot.live:
-                    continue
+            live = [s for s in self._slots if s.live]
+            for slot in live:
                 if slot.pos >= self.max_seq:
                     raise RuntimeError(
                         f"slot {slot.sid!r} context full ({slot.pos} >= "
                         f"max_seq {self.max_seq})")
+            self.cache.update(new_state)
+            self.epoch += 1
+            at = np.full(self.num_slots, self.max_seq, np.int32)
+            for slot in live:
+                at[slot.index] = slot.pos
+            self.carry, self._next = _commit_rows(
+                self.carry, bounds, logits, timing.upload(at))
+            timing.count("carry_rows", len(live))
+            for slot in live:
                 self.tokens[slot.index, slot.pos] = tok[slot.index, 0]
-                self.bounds[:, slot.index, slot.pos] = b[:, slot.index, 0]
-                self.last_logits[slot.index] = lg[slot.index]
                 slot.pos += 1
                 slot.epoch = self.epoch
 
@@ -257,11 +316,9 @@ class SessionManager:
             with timing.span("admit.place"), self._lock:
                 j = self._find_slot()
                 slot = self._slots[j]
-                self._place(caches, j)
-                b, lg = timing.fetch_all((bounds, logits))
-                self.bounds[:, j] = b[:, 0]
+                self._place({**caches, "bounds": bounds, "logits": logits},
+                            j)
                 self.tokens[j] = row[0]
-                self.last_logits[j] = lg[0]
                 if sid is None:
                     sid = f"s{self._next_sid}"
                     self._next_sid += 1
@@ -346,18 +403,22 @@ class SessionManager:
             self._park_slot(j)
 
     def _place(self, rows: Dict[str, Any], j: int) -> None:  # holds: _lock
-        """Row ``j`` of the entries ``rows`` names set to ``rows[k][0]``,
-        in one program."""
-        self.cache.update(_place_rows({k: self.cache[k] for k in rows},
-                                      rows, np.int32(j)))
+        """Row ``j`` of every state and carry entry set to ``rows[k]``, in
+        one program."""
+        cache, self.carry = _place_rows(self.cache, self.carry, rows,
+                                        np.int32(j))
+        self.cache.update(cache)
+        self._next = None
         timing.count("slot_rows")
 
     def _park_slot(self, j: int) -> None:    # holds: _lock
         slot = self._slots[j]
         # take and clear dispatch before the one fetch waits on the take
-        taken = _take_rows(self.cache, np.int32(j))
+        taken = _take_rows({**self.cache, **self.carry}, np.int32(j))
         timing.count("slot_rows")
-        self.cache.update(_clear_rows(self.cache, np.int32(j)))
+        cache, self.carry = _clear_rows(self.cache, self.carry, np.int32(j))
+        self.cache.update(cache)
+        self._next = None
         timing.count("slot_rows")
         rows = timing.fetch_all(taken)
         state: Dict[str, tuple] = {}
@@ -370,13 +431,11 @@ class SessionManager:
         self._parked[slot.sid] = {
             "state": state,
             "tokens": self.tokens[j, :slot.pos].copy(),
-            "bounds": self.bounds[:, j, :slot.pos].copy(),
-            "logits": self.last_logits[j].copy(),
+            "bounds": rows["bounds"][:, :slot.pos],   # (U, pos, D)
+            "logits": rows["logits"],
             "pos": slot.pos,
         }
         self.tokens[j] = 0
-        self.bounds[:, j] = 0
-        self.last_logits[j] = 0
         self.epoch += 1
         slot.sid, slot.live, slot.pos, slot.epoch = None, False, 0, -1
 
@@ -397,10 +456,13 @@ class SessionManager:
                     full[:, :arr.shape[1]] = arr
                     arr = full
                 rows[k] = arr[None]
+            b = parked["bounds"]
+            rows["bounds"] = np.zeros(self.carry["bounds"].shape[1:],
+                                      b.dtype)
+            rows["bounds"][:, :pos] = b
+            rows["logits"] = parked["logits"]
             self._place(timing.upload_all(rows), j)
             self.tokens[j, :pos] = parked["tokens"]
-            self.bounds[:, j, :pos] = parked["bounds"]
-            self.last_logits[j] = parked["logits"]
             self.epoch += 1
             slot.sid, slot.live, slot.pos, slot.epoch = sid, True, pos, \
                 self.epoch
@@ -421,9 +483,10 @@ class SessionManager:
         with self._lock:
             return dataclasses.replace(self._slots[self._slot_index(sid)])
 
-    def logits_for(self, sid: str):
+    def logits_for(self, sid: str) -> np.ndarray:
         with self._lock:
-            return self.last_logits[self._slot_index(sid)].copy()
+            row = self.carry["logits"][self._slot_index(sid)]
+        return timing.fetch(row)
 
     def tokens_for(self, sid: str) -> np.ndarray:
         with self._lock:
@@ -480,7 +543,7 @@ class SessionManager:
         r = self.runner
         fn = r.recompute_fn(lo, hi)          # runner lock first (42 < 47)
         with self._lock:
-            x = timing.upload(self.bounds[lo])           # (B, max_seq, D)
+            x = self.carry["bounds"][:, lo]              # (B, max_seq, D)
             tokens = timing.upload(self.tokens)
             lengths = timing.upload(
                 np.asarray([s.pos for s in self._slots], np.int32))
@@ -520,11 +583,14 @@ class SessionManager:
 
     # -- test/benchmark support -------------------------------------------
     def snapshot(self) -> dict:
+        """The pool's state; ``bounds`` ``(num_slots, U, max_seq, D)`` and
+        ``logits`` are device copies, since later steps donate the
+        carry."""
         with self._lock:
             return {"cache": dict(self.cache),
                     "tokens": self.tokens.copy(),
-                    "bounds": self.bounds.copy(),
-                    "logits": self.last_logits.copy(),
+                    "bounds": self.carry["bounds"].copy(),
+                    "logits": self.carry["logits"].copy(),
                     "slots": [dataclasses.replace(s) for s in self._slots],
                     "parked": dict(self._parked),
                     "epoch": self.epoch, "clock": self._clock}
@@ -533,8 +599,9 @@ class SessionManager:
         with self._lock:
             self.cache = dict(snap["cache"])
             self.tokens = snap["tokens"].copy()
-            self.bounds = snap["bounds"].copy()
-            self.last_logits = snap["logits"].copy()
+            # copies: the carry is donated, the snapshot must outlive it
+            self.carry = {k: jnp.array(snap[k]) for k in ("bounds", "logits")}
+            self._next = None
             self._slots = [dataclasses.replace(s) for s in snap["slots"]]
             self._parked = dict(snap["parked"])
             self.epoch, self._clock = snap["epoch"], snap["clock"]

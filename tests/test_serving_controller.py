@@ -50,6 +50,31 @@ def test_batching_server_breaks_decode_loop_when_all_done():
     assert len(out[0]) == 8 and out[1] == [2] * 8
 
 
+def test_batching_server_migrates_mid_stream():
+    """``export_state`` after two decode steps is host numpy (the slot
+    pool's device carry included), and a second server that imports it
+    finishes every request with the tokens of an unmigrated run."""
+    cfg = get_config("qwen2.5-3b").reduced()
+    params = T.init_model(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, cfg.vocab_size, (5 + 2 * i,))
+               for i in range(3)]
+
+    def reqs():
+        return [Request(rid=i, prompt=p, max_new_tokens=6)
+                for i, p in enumerate(prompts)]
+
+    whole = BatchingServer(cfg, params, max_seq=64).run_batch(reqs())
+    src = BatchingServer(cfg, params, max_seq=64)
+    first = reqs()
+    src.run_batch(first, max_steps=2)
+    state = src.export_state(first)
+    assert all(isinstance(state[k], np.ndarray)
+               for k in ("tok", "bounds", "logits"))
+    dst = BatchingServer(cfg, params, max_seq=64)
+    assert dst.run_batch(dst.import_state(state), resume=True) == whole
+
+
 def test_frame_source_rate():
     cfg = get_config("qwen2.5-3b").reduced()
     src = FrameSource(cfg, fps=10, seq=8)

@@ -281,6 +281,174 @@ def test_batch_recompute_preserves_eight_ragged_sessions(arch):
 
 
 # ---------------------------------------------------------------------------
+# the step carry on the device: bit-identical to host bookkeeping
+# ---------------------------------------------------------------------------
+
+class _HostCarry:
+    """The step carry as host numpy bookkeeping: each live row's
+    checkpoints, logits and token written one by one, the next token the
+    argmax of the host logits, a parked row saved and zeroed.  Replayed
+    beside a pool on the same stage outputs, it is the path the device
+    carry replaced."""
+
+    def __init__(self, sm):
+        self.bounds = np.zeros(sm.carry["bounds"].shape,
+                               sm.carry["bounds"].dtype)  # (B, U, S, D)
+        self.logits = np.zeros(sm.carry["logits"].shape, np.float32)
+        self.tokens = np.zeros((sm.num_slots, sm.max_seq), np.int32)
+        self.parked = {}
+
+    def admit(self, sm, sid, prompt):
+        j, L = sm.slot_info(sid).index, len(prompt)
+        row = np.zeros((1, sm.max_seq), np.int32)
+        row[0, :L] = prompt
+        r = sm.runner
+        logits, _, bounds = r.admit_fn()(r.params, row, np.int32(L))
+        self.bounds[j] = np.asarray(bounds)[:, 0]
+        self.logits[j] = np.asarray(logits)[0]
+        self.tokens[j] = row[0]
+
+    def commit(self, sm, token, bounds, logits):
+        """Called with the pool's pre-step positions."""
+        tok = np.asarray(token)
+        np.testing.assert_array_equal(
+            tok, np.argmax(self.logits, -1)[:, None])   # the next token
+        b, lg = np.asarray(bounds), np.asarray(logits)
+        for sid in sm.session_ids():
+            j, pos = sm.slot_info(sid).index, sm.slot_info(sid).pos
+            self.tokens[j, pos] = tok[j, 0]
+            self.bounds[j, :, pos] = b[:, j, 0]
+            self.logits[j] = lg[j]
+
+    def park(self, j, sid):
+        self.parked[sid] = (self.bounds[j].copy(), self.logits[j].copy(),
+                            self.tokens[j].copy())
+        self.bounds[j], self.logits[j], self.tokens[j] = 0, 0, 0
+
+    def readmit(self, j, sid):
+        self.bounds[j], self.logits[j], self.tokens[j] = \
+            self.parked.pop(sid)
+
+    def check(self, sm):
+        np.testing.assert_array_equal(np.asarray(sm.carry["bounds"]),
+                                      self.bounds)
+        np.testing.assert_array_equal(np.asarray(sm.carry["logits"]),
+                                      self.logits)
+        for sid in sm.session_ids():
+            j, pos = sm.slot_info(sid).index, sm.slot_info(sid).pos
+            np.testing.assert_array_equal(sm.logits_for(sid),
+                                          self.logits[j])
+            np.testing.assert_array_equal(sm.tokens_for(sid),
+                                          self.tokens[j, :pos])
+
+
+def _carried_pool(arch, monkeypatch):
+    """A four-slot pool behind a split pipeline, three sessions admitted,
+    the host bookkeeping replayed beside it at every commit."""
+    cfg = _cfg(arch, 4 if arch == "zamba2-7b" else 2)
+    mgr, sm = make_session_manager(cfg, split=1, net=NetworkModel(1000.0),
+                                   num_slots=4, max_seq=32, seed=0)
+    host = _HostCarry(sm)
+    orig = SessionManager.commit_step
+
+    def commit_step(self, token, new_state, bounds, logits):
+        host.commit(self, token, bounds, logits)
+        orig(self, token, new_state, bounds, logits)
+    monkeypatch.setattr(SessionManager, "commit_step", commit_step)
+    for p in _ragged(cfg, (5, 9, 3), seed=12):
+        host.admit(sm, sm.admit(p), p)
+    return mgr, sm, host
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "zamba2-7b"])
+def test_device_carry_bit_identical_to_host_bookkeeping(arch, monkeypatch):
+    """Tokens, ``logits_for`` and every checkpoint row equal the host
+    bookkeeping's exactly, through steps, a park and a readmit into
+    another slot; the dead slot's rows stay zero throughout."""
+    mgr, sm, host = _carried_pool(arch, monkeypatch)
+
+    def dead_rows_zero(j):
+        assert not np.asarray(sm.carry["bounds"])[j].any()
+        assert not np.asarray(sm.carry["logits"])[j].any()
+
+    for _ in range(3):
+        mgr.active.process()
+        host.check(sm)
+        dead_rows_zero(3)
+    a = sm.session_ids()[0]
+    j = sm.slot_info(a).index
+    sm.evict(a)
+    host.park(j, a)
+    host.check(sm)
+    mgr.active.process()
+    host.check(sm)
+    dead_rows_zero(j)
+    c = _ragged(sm.cfg, (7,), seed=15)[0]
+    host.admit(sm, sm.admit(c), c)           # into the parked slot
+    mgr.active.process()
+    sm.readmit(a)                            # into the one left dead
+    assert sm.slot_info(a).index == 3
+    host.readmit(3, a)
+    host.check(sm)
+    for _ in range(2):
+        mgr.active.process()
+        host.check(sm)
+    mgr.close()
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "zamba2-7b"])
+def test_recompute_from_device_checkpoints(arch, monkeypatch):
+    """``recompute_layers`` reads the device checkpoints: every live
+    slot's rebuilt state equals its decoded state (to the float tolerance
+    of the other recompute tests: a prefill and a decode step order their
+    sums differently; a dead slot's row holds its masked garbage, and is
+    rebuilt to zero), and the served tokens carry on equal to the host
+    bookkeeping's."""
+    mgr, sm, host = _carried_pool(arch, monkeypatch)
+    for _ in range(3):
+        mgr.active.process()
+    live = [sm.slot_info(s).index for s in sm.session_ids()]
+    nu = sm.runner.num_units
+    decoded = {k: np.asarray(v) for k, v in sm.subset(1, nu).items()}
+    sm.recompute_layers(1, nu)
+    for k, v in sm.subset(1, nu).items():
+        np.testing.assert_allclose(np.asarray(v)[live], decoded[k][live],
+                                   atol=1e-4, err_msg=k)
+    for _ in range(2):
+        mgr.active.process()
+        host.check(sm)
+    mgr.close()
+
+
+def test_snapshot_survives_donating_steps(tf_runner):
+    """Steps, admissions and parks donate the carry; a snapshot taken
+    before them still reads, and restoring it (twice) replays the same
+    continuation."""
+    sm = SessionManager(tf_runner, num_slots=3)
+    a, b = [sm.admit(p) for p in _ragged(tf_runner.cfg, (4, 6), seed=13)]
+    sm.decode_step()
+    snap = sm.snapshot()
+    bounds0 = np.asarray(snap["bounds"]).copy()
+    for _ in range(2):
+        sm.decode_step()
+    sm.evict(a)
+    sm.admit(_ragged(tf_runner.cfg, (5,), seed=14)[0])
+    sm.decode_step()
+    np.testing.assert_array_equal(np.asarray(snap["bounds"]), bounds0)
+    runs = []
+    for _ in range(2):
+        sm.restore(snap)
+        for _ in range(2):
+            sm.decode_step()
+        runs.append({s: (sm.logits_for(s), sm.tokens_for(s))
+                     for s in (a, b)})
+    for s in (a, b):
+        np.testing.assert_array_equal(runs[0][s][0], runs[1][s][0])
+        np.testing.assert_array_equal(runs[0][s][1], runs[1][s][1])
+    np.testing.assert_array_equal(np.asarray(snap["bounds"]), bounds0)
+
+
+# ---------------------------------------------------------------------------
 # engine integration: scheduled admission + per-session attribution
 # ---------------------------------------------------------------------------
 

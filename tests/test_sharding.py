@@ -297,3 +297,42 @@ def test_stateful_mesh_roundtrip_decodes_identically():
     np.testing.assert_array_equal(toks, ref_toks)
     for a, b in zip(out, ref):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@needs_devices
+def test_slot_pool_mesh_roundtrip_decodes_identically():
+    """A slot pool's step carry lives on the edge device: with a
+    mid-stream hop onto a 2-way mesh and back, the sharded cloud stage's
+    bounds and logits reach the commit there, and every session is served
+    the tokens of a pool that never left one device."""
+    from repro.serving import make_session_manager
+    cfg = get_config("qwen2.5-3b").reduced()
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (6, 9)]
+
+    def run(hop):
+        mgr, sm = make_session_manager(cfg, split=1, net=NetworkModel(50.0),
+                                       num_slots=3, max_seq=32, seed=3)
+        try:
+            sids = [sm.admit(p) for p in prompts]
+            mgr.serve({})
+            if hop:
+                mgr.set_mesh_shape((2,))
+                assert mgr.repartition("switch_b2", 1).new_mesh == (2,)
+            mgr.serve({})
+            for v in sm.carry.values():          # still on the edge device
+                assert v.sharding.device_set == {jax.devices()[0]}
+            if hop:
+                mgr.set_mesh_shape(None)
+                assert mgr.repartition("switch_b2", 1).mesh_change
+            for _ in range(2):
+                mgr.serve({})
+            return {s: (sm.tokens_for(s), sm.logits_for(s)) for s in sids}
+        finally:
+            mgr.close()
+
+    ref, out = run(False), run(True)
+    for s, (toks, logits) in ref.items():
+        np.testing.assert_array_equal(out[s][0], toks)
+        np.testing.assert_allclose(out[s][1], logits, rtol=1e-4, atol=1e-4)

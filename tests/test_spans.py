@@ -207,11 +207,12 @@ def test_decode_step_spans_and_host_syncs(rec, lm):
     order = sorted(kids.values(), key=lambda r: r.start_ns)
     assert [r.name for r in order] == ["step.input", "step.edge",
                                        "step.cloud", "step.commit"]
-    # 2 block_until_ready + the token, bounds and logits brought back
-    assert total(step, "host_sync", recs) == 5
-    assert kids["step.commit"].attrs["d2h_bytes"] > 0
-    assert kids["step.input"].attrs["h2d_bytes"] \
-        == sm.last_logits.nbytes + 4 * sm.num_slots
+    # 2 block_until_ready + the (num_slots, 1) token brought back: the
+    # logits and boundary checkpoints stay on the device
+    assert total(step, "host_sync", recs) == 3
+    assert kids["step.commit"].attrs["d2h_bytes"] == 4 * sm.num_slots
+    assert kids["step.input"].attrs.get("h2d_bytes", 0) <= 4 * sm.num_slots
+    assert kids["step.commit"].attrs["carry_rows"] == len(sm.session_ids())
     pipe = mgr.pool.active
     assert req.t_edge == kids["step.edge"].wall * pipe.edge_scale
     assert req.t_cloud == kids["step.cloud"].wall
@@ -340,7 +341,8 @@ def test_slot_rows_move_in_one_program_compiled_once(rec, lm):
     mgr, sm = _sessions(lm)
     for place in named("admit.place"):
         assert place.attrs["slot_rows"] == 1
-        assert place.attrs.get("host_sync", 0) <= 1
+        assert place.attrs.get("host_sync", 0) == 0
+        assert place.attrs.get("d2h_bytes", 0) == 0
     sm.evict(sm.session_ids()[0])
     park = one("sessions.park")
     assert park.attrs["slot_rows"] == 2 and park.attrs["host_sync"] == 1
@@ -354,6 +356,29 @@ def test_slot_rows_move_in_one_program_compiled_once(rec, lm):
     reuse = one("reuse")
     assert total(reuse, "backend_compile", timing.records()) == 0
     assert total(reuse, "slot_rows", timing.records()) == 3 * (1 + 2)
+    mgr.close()
+
+
+def test_step_commit_compiles_once_across_steps_and_slot_changes(rec, lm):
+    """After the first step, decode steps compile nothing: not as the
+    live slots change (an admission into a free slot, a session ended),
+    and each commit writes exactly the live rows into the carry."""
+    CacheEvents()
+    mgr, sm = _sessions(lm)
+    mgr.serve({})
+    rng = np.random.default_rng(2)
+    timing.clear()
+    mgr.serve({})
+    sm.evict(sm.session_ids()[0])
+    mgr.serve({})
+    sm.admit(rng.integers(0, lm[0].vocab_size, 4).astype(np.int32))
+    mgr.serve({})
+    recs = timing.records()
+    steps = named("step")
+    assert len(steps) == 3
+    assert sum(total(st, "backend_compile", recs) for st in steps) == 0
+    assert [r.attrs["carry_rows"] for r in named("step.commit")] \
+        == [2, 1, 2]
     mgr.close()
 
 

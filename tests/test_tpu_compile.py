@@ -1,5 +1,7 @@
 """The decode path's Pallas kernels compile for a TPU v5e at published
-widths, and the sharded cloud stage compiles with them over four chips.
+widths, and the sharded cloud stage compiles with them over four chips;
+the decode ranges and the slot pool's carry programs keep their
+temporaries small.
 
 Nothing here runs: each case compiles for a described (not attached)
 ``v5e:2x2`` topology and asserts the Mosaic kernel is in the executable
@@ -191,3 +193,31 @@ def test_hybrid_decode_range_reads_f32_weights_per_layer(one_chip, on_tpu,
     assert "tpu_custom_call" in text
     assert not re.search(r"bf16\[([2-9]|\d\d+),3584,14704\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("program", ["commit", "place", "clear"])
+def test_slot_carry_programs_write_in_place(one_chip, program):
+    """The slot pool's step carry at qwen2.5-3b's 4 x 512 (boundary
+    checkpoints 0.60 GB, logits 2.4 MB) is donated to the programs that
+    write it: each aliases the whole carry and writes its rows in place,
+    with no temporary copy of it (a scatter over the checkpoints' slot
+    and position axes made one of 0.67 GB)."""
+    from repro.serving import sessions as S
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    B, U, L, D, V = 4, 36, 512, 2048, 151936
+    carry = {"bounds": sd((B, U, L, D)), "logits": sd((B, V))}
+    cache = {n: sd((B, 2, L, 128)) for n in ("k0", "v0")}
+    j = sd((), jnp.int32)
+    if program == "commit":
+        lowered = S._commit_rows.lower(carry, sd((U, B, 1, D)), sd((B, V)),
+                                       sd((B,), jnp.int32))
+    elif program == "place":
+        rows = {"k0": sd((1, 2, L, 128)), "v0": sd((1, 2, L, 128)),
+                "bounds": sd((U, 1, L, D)), "logits": sd((1, V))}
+        lowered = S._place_rows.lower(cache, carry, rows, j)
+    else:
+        lowered = S._clear_rows.lower(cache, carry, j)
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * (B * U * L * D + B * V)
+    assert mem.temp_size_in_bytes < 2**20
